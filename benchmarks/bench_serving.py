@@ -401,86 +401,104 @@ def bench_speculative_tokps(results: list):
     assert speedup >= 1.3, (base_tps, spec_tps)
 
 
+def tp_capacity_report() -> dict:
+    """Serve the TP=2 capacity and bit-identity workloads on the first two
+    visible devices and return the numbers ``bench_tp_capacity`` checks."""
+    import dataclasses
+
+    import jax
+
+    from repro.launch.mesh import make_mesh
+
+    assert len(jax.devices()) >= 2, jax.devices()
+    # float32: TP reductions run in f32, so greedy decode is bit-identical
+    # across TP degrees for f32 models; bf16 activations quantize logits
+    # to ~1e-2 ulps and a reassociated sum can flip an exact near-tie argmax
+    cfg = dataclasses.replace(get_reduced_config("stablelm-3b"),
+                              dtype="float32")
+    params = init_params(cfg, 0)
+    cache_len, page = 128, 16
+    budget_lines = 512                  # per-DEVICE HBM budget in KV lines
+
+    def serve(mesh, usable_pages, n_req, max_new):
+        rng = np.random.default_rng(3)
+        eng = DecodeEngine(cfg, params, num_slots=n_req,
+                           cache_len=cache_len, decode_chunk=4,
+                           prefill_buckets="auto", kv_page_size=page,
+                           kv_pages=usable_pages + 1, mesh=mesh)
+        reqs = [Request(rid=i,
+                        prompt=rng.integers(0, cfg.vocab_size, 12).astype(
+                            np.int32), max_new_tokens=max_new)
+                for i in range(n_req)]
+        for r in reqs:
+            eng.submit(r)
+        peak, t0 = 0, time.perf_counter()
+        for _ in range(5_000):
+            n = eng.step()
+            peak = max(peak, eng.active())
+            if n == 0:
+                break
+        return (peak, time.perf_counter() - t0,
+                [list(r.output) for r in reqs], eng)
+
+    # bit-identity on a starvation-free workload (pool covers every
+    # request on both sides): the guarantee is per-schedule — a starved
+    # pool requeues, and the resume re-prefills the partial through a
+    # different (bucketed) program whose f32 reassociation is not bitwise
+    # the incremental decode, independent of TP
+    _, _, base_out, _ = serve(None, 64, 8, 12)
+    _, _, tp_out, _ = serve(make_mesh(1, 2), 64, 8, 12)
+
+    # capacity at equal per-device HBM: one device's budget IS the pool;
+    # two shards hold half a page each, so the same budget backs 2x pages
+    base_peak, base_dt, _, _ = serve(None, budget_lines // page, 48, 24)
+    tp_peak, tp_dt, _, eng = serve(make_mesh(1, 2),
+                                   2 * (budget_lines // page), 48, 24)
+    return {
+        "base_peak": base_peak, "base_dt": base_dt,
+        "tp_peak": tp_peak, "tp_dt": tp_dt,
+        "identical": tp_out == base_out,
+        "high_water": eng.allocator.high_water,
+        "tp_pages": eng.paging.usable_pages,
+        "plan": eng.tp_stats()["plan"],
+    }
+
+
 def bench_tp_capacity(results: list):
     """The tensor-parallel serving headline claim: sharding the paged KV
     pool along KV heads puts HALF of every page on each of 2 devices, so
     the SAME per-device HBM budget backs 2x the logical pages and
     >= 1.8x the concurrent short requests — with greedy outputs
-    bit-identical to TP=1.  TP >= 2 needs real devices and this process
-    pinned the platform to one at import, so the measurement runs in a
-    subprocess with 2 forced host devices (the repo's multi-device CPU
-    recipe); this process parses its JSON report."""
-    import json
-    import os
-    import subprocess
-    import sys
-    code = r'''
-import dataclasses, json, time
-import numpy as np, jax
-assert len(jax.devices()) == 2, jax.devices()
-from repro.configs import get_reduced_config
-from repro.launch.mesh import make_mesh
-from repro.models import init_params
-from repro.serving import DecodeEngine, Request
+    bit-identical to TP=1.
 
-# float32: TP reductions run in f32, so greedy decode is bit-identical
-# across TP degrees for f32 models; bf16 activations quantize logits to
-# ~1e-2 ulps and a reassociated sum can flip an exact near-tie argmax
-cfg = dataclasses.replace(get_reduced_config("stablelm-3b"),
-                          dtype="float32")
-params = init_params(cfg, 0)
-cache_len, page = 128, 16
-budget_lines = 512                      # per-DEVICE HBM budget in KV lines
-
-def serve(mesh, usable_pages, n_req, max_new):
-    rng = np.random.default_rng(3)
-    eng = DecodeEngine(cfg, params, num_slots=n_req, cache_len=cache_len,
-                       decode_chunk=4, prefill_buckets="auto",
-                       kv_page_size=page, kv_pages=usable_pages + 1,
-                       mesh=mesh)
-    reqs = [Request(rid=i,
-                    prompt=rng.integers(0, cfg.vocab_size, 12).astype(
-                        np.int32), max_new_tokens=max_new)
-            for i in range(n_req)]
-    for r in reqs:
-        eng.submit(r)
-    peak, t0 = 0, time.perf_counter()
-    for _ in range(5_000):
-        n = eng.step()
-        peak = max(peak, eng.active())
-        if n == 0:
-            break
-    return (peak, time.perf_counter() - t0,
-            [list(r.output) for r in reqs], eng)
-
-# bit-identity on a starvation-free workload (pool covers every request
-# on both sides): the guarantee is per-schedule — a starved pool
-# requeues, and the resume re-prefills the partial through a different
-# (bucketed) program whose f32 reassociation is not bitwise the
-# incremental decode, independent of TP
-_, _, base_out, _ = serve(None, 64, 8, 12)
-_, _, tp_out, _ = serve(make_mesh(1, 2), 64, 8, 12)
-
-# capacity at equal per-device HBM: one device's budget IS the pool;
-# two shards hold half a page each, so the same budget backs 2x pages
-base_peak, base_dt, _, _ = serve(None, budget_lines // page, 48, 24)
-tp_peak, tp_dt, _, eng = serve(make_mesh(1, 2),
-                               2 * (budget_lines // page), 48, 24)
-print(json.dumps({
-    "base_peak": base_peak, "base_dt": base_dt,
-    "tp_peak": tp_peak, "tp_dt": tp_dt,
-    "identical": tp_out == base_out,
-    "high_water": eng.allocator.high_water,
-    "tp_pages": eng.paging.usable_pages,
-    "plan": eng.tp_stats()["plan"],
-}))
-'''
-    env = dict(os.environ, PYTHONPATH="src",
-               XLA_FLAGS="--xla_force_host_platform_device_count=2")
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, env=env)
-    assert r.returncode == 0, (r.stdout[-1000:], r.stderr[-2000:])
-    rep = json.loads(r.stdout.splitlines()[-1])
+    With 2+ devices visible it runs in this process.  On a one-device
+    CPU backend it runs in a child pinned to the CPU with 2 forced host
+    devices (a device count is fixed once jax starts); this process
+    parses the child's JSON report.  A single accelerator cannot be
+    shared with a child, so there the claim is not measured."""
+    import jax
+    if len(jax.devices()) >= 2:
+        rep = tp_capacity_report()
+    elif jax.default_backend() == "cpu":
+        import json
+        import os
+        import subprocess
+        import sys
+        from pathlib import Path
+        root = Path(__file__).resolve().parents[1]
+        env = dict(os.environ, PYTHONPATH=f"{root / 'src'}:{root}",
+                   JAX_PLATFORMS="cpu",
+                   XLA_FLAGS="--xla_force_host_platform_device_count=2")
+        code = ("import json; from benchmarks.bench_serving import "
+                "tp_capacity_report; print(json.dumps(tp_capacity_report()))")
+        r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                           text=True, env=env, cwd=root)
+        assert r.returncode == 0, (r.stdout[-1000:], r.stderr[-2000:])
+        rep = json.loads(r.stdout.splitlines()[-1])
+    else:
+        print(f"serving_tp_capacity: needs 2 devices, 1 "
+              f"{jax.default_backend()} device visible — not measured")
+        return
     ratio = rep["tp_peak"] / rep["base_peak"]
     results.append(("serving_tp_capacity", rep["tp_dt"] * 1e6,
                     f"peak {rep['tp_peak']} concurrent on 2 shards vs "

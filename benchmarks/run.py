@@ -38,6 +38,8 @@ def main(argv=None) -> int:
                          "the OLD baseline first")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (
         bench_kernels, bench_parallelism, bench_roofline, bench_scheduler,
         bench_serving,

@@ -18,11 +18,7 @@ import math
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-try:                                    # jax >= 0.5.0 only
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 from repro.cluster.cluster import Cluster
 from repro.cluster.job import Job
@@ -52,7 +48,5 @@ def mesh_for_job(cluster: Cluster, job: Job, model_parallel: int = 1,
         n_chips = 2 ** int(math.log2(n_chips))
     data, model = factor_mesh(n_chips, model_parallel)
     dev = np.asarray(devices[:data * model]).reshape(data, model)
-    if AxisType is None:
-        return Mesh(dev, ("data", "model"))
     return Mesh(dev, ("data", "model"),
                 axis_types=(AxisType.Auto, AxisType.Auto))

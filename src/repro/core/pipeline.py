@@ -18,19 +18,10 @@ from __future__ import annotations
 
 import functools
 
-import inspect
-
 import jax
 import jax.numpy as jnp
-try:                                    # jax >= 0.5 exports it at top level
-    from jax import shard_map
-except ImportError:                     # jax 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# the replication-check kwarg was renamed check_rep -> check_vma
-_CHECK_KW = ("check_vma" if "check_vma" in
-             inspect.signature(shard_map).parameters else "check_rep")
+from jax import shard_map
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 def split_stages(stacked, n_stages: int):
@@ -62,7 +53,7 @@ def pipeline_apply(stage_fn, stacked_params, x_micro, mesh: Mesh,
         shard_map, mesh=mesh,
         in_specs=(p_params, P()),
         out_specs=P(),
-        **{_CHECK_KW: False})
+        check_vma=False)
     def run(params_local, x_local):
         my = jax.lax.axis_index(axis)
         is_first = my == 0
@@ -96,4 +87,7 @@ def pipeline_apply(stage_fn, stacked_params, x_micro, mesh: Mesh,
 
 def make_pipeline_mesh(n_stages: int, devices=None) -> Mesh:
     devices = devices if devices is not None else jax.devices()[:n_stages]
-    return jax.make_mesh((n_stages,), ("pipe",), devices=devices)
+    # Auto, not make_mesh's default Explicit axes: the stage pytree is laid
+    # out with NamedSharding and moved by shard_map, not by sharding-in-types
+    return jax.make_mesh((n_stages,), ("pipe",), devices=devices,
+                         axis_types=(AxisType.Auto,))

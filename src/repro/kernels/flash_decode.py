@@ -49,6 +49,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_K = 512
 _NEG_INF = -1e30
+#: the per-chunk softmax max/sum partials are written broadcast over one
+#: lane-width trailing axis: Mosaic tiles the last two block dims by
+#: (8, 128) unless they span the whole array, and a (G,) row per grid cell
+#: over an ``nk``-long axis fits neither rule
+_LANES = 128
 
 
 def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
@@ -88,8 +93,21 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
         preferred_element_type=jnp.float32)           # (G, Dh)
 
     o_ref[0, 0, 0] = acc
-    m_ref[0, 0, 0] = m
-    l_ref[0, 0, 0] = l
+    m_ref[0, 0, 0] = jnp.broadcast_to(m[:, None], (G, _LANES))
+    l_ref[0, 0, 0] = jnp.broadcast_to(l[:, None], (G, _LANES))
+
+
+def _combine(o_part, m_part, l_part, dtype):
+    """Cross-block combine: rescale every chunk's partial to the global
+    max and normalize.  o_part: (B, K, n, G, Dh); m/l: (B, K, n, G, lanes),
+    every lane holding the same value."""
+    m_part, l_part = m_part[..., 0], l_part[..., 0]
+    m_star = jnp.max(m_part, axis=2, keepdims=True)          # (B, K, 1, G)
+    w = jnp.exp(m_part - m_star)                             # (B, K, n, G)
+    num = jnp.sum(o_part * w[..., None], axis=2)             # (B, K, G, Dh)
+    den = jnp.sum(l_part * w, axis=2)                        # (B, K, G)
+    den = jnp.where(den == 0.0, 1.0, den)
+    return (num / den[..., None]).astype(dtype)
 
 
 @functools.partial(jax.jit,
@@ -128,27 +146,21 @@ def flash_decode_bkgd(q, k, v, pos, *, block_k: int = DEFAULT_BLOCK_K,
             out_specs=[
                 pl.BlockSpec((1, 1, 1, G, Dh),
                              lambda b, h, ki, pos: (b, h, ki, 0, 0)),
-                pl.BlockSpec((1, 1, 1, G),
-                             lambda b, h, ki, pos: (b, h, ki, 0)),
-                pl.BlockSpec((1, 1, 1, G),
-                             lambda b, h, ki, pos: (b, h, ki, 0)),
+                pl.BlockSpec((1, 1, 1, G, _LANES),
+                             lambda b, h, ki, pos: (b, h, ki, 0, 0)),
+                pl.BlockSpec((1, 1, 1, G, _LANES),
+                             lambda b, h, ki, pos: (b, h, ki, 0, 0)),
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((B, K, nk, G, Dh), jnp.float32),
-            jax.ShapeDtypeStruct((B, K, nk, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, K, nk, G), jnp.float32),
+            jax.ShapeDtypeStruct((B, K, nk, G, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B, K, nk, G, _LANES), jnp.float32),
         ],
         interpret=interpret,
     )(pos.astype(jnp.int32), q, k, v)
 
-    # cross-block combine: rescale every chunk's partial to the global max
-    m_star = jnp.max(m_part, axis=2, keepdims=True)          # (B, K, 1, G)
-    w = jnp.exp(m_part - m_star)                             # (B, K, nk, G)
-    num = jnp.sum(o_part * w[..., None], axis=2)             # (B, K, G, Dh)
-    den = jnp.sum(l_part * w, axis=2)                        # (B, K, G)
-    den = jnp.where(den == 0.0, 1.0, den)
-    return (num / den[..., None]).astype(q.dtype)
+    return _combine(o_part, m_part, l_part, q.dtype)
 
 
 # ----------------------------------------------------------- paged cache ----
@@ -192,8 +204,8 @@ def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         preferred_element_type=jnp.float32)           # (G, Dh)
 
     o_ref[0, 0, 0] = acc
-    m_ref[0, 0, 0] = m
-    l_ref[0, 0, 0] = l
+    m_ref[0, 0, 0] = jnp.broadcast_to(m[:, None], (G, _LANES))
+    l_ref[0, 0, 0] = jnp.broadcast_to(l[:, None], (G, _LANES))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -233,23 +245,18 @@ def flash_decode_paged_bkgd(q, k, v, page_table, pos, *,
             out_specs=[
                 pl.BlockSpec((1, 1, 1, G, Dh),
                              lambda b, h, pi, pt, pos: (b, h, pi, 0, 0)),
-                pl.BlockSpec((1, 1, 1, G),
-                             lambda b, h, pi, pt, pos: (b, h, pi, 0)),
-                pl.BlockSpec((1, 1, 1, G),
-                             lambda b, h, pi, pt, pos: (b, h, pi, 0)),
+                pl.BlockSpec((1, 1, 1, G, _LANES),
+                             lambda b, h, pi, pt, pos: (b, h, pi, 0, 0)),
+                pl.BlockSpec((1, 1, 1, G, _LANES),
+                             lambda b, h, pi, pt, pos: (b, h, pi, 0, 0)),
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((B, K, n_pages, G, Dh), jnp.float32),
-            jax.ShapeDtypeStruct((B, K, n_pages, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, K, n_pages, G), jnp.float32),
+            jax.ShapeDtypeStruct((B, K, n_pages, G, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B, K, n_pages, G, _LANES), jnp.float32),
         ],
         interpret=interpret,
     )(page_table.astype(jnp.int32), pos.astype(jnp.int32), q, k, v)
 
-    m_star = jnp.max(m_part, axis=2, keepdims=True)          # (B, K, 1, G)
-    w = jnp.exp(m_part - m_star)                             # (B, K, np, G)
-    num = jnp.sum(o_part * w[..., None], axis=2)             # (B, K, G, Dh)
-    den = jnp.sum(l_part * w, axis=2)                        # (B, K, G)
-    den = jnp.where(den == 0.0, 1.0, den)
-    return (num / den[..., None]).astype(q.dtype)
+    return _combine(o_part, m_part, l_part, q.dtype)

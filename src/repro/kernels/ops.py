@@ -101,7 +101,9 @@ def ssd_scan(x, dt, A, Bm, Cm, chunk: int = 256, interpret=None):
     B, S, H, P = x.shape
     Q = _pick_block(S, chunk)
     xt = x.transpose(0, 2, 1, 3)                     # (B,H,S,P)
-    dtt = dt.astype(jnp.float32).transpose(0, 2, 1)  # (B,H,S)
-    a = dtt * A.astype(jnp.float32)[None, :, None]   # (B,H,S)
+    # dt/a ride as (B,H,S,1) columns: a (1, chunk) block of a (B,H,S)
+    # array breaks Mosaic's (8, 128) tiling rule for the last two dims
+    dtt = dt.astype(jnp.float32).transpose(0, 2, 1)[..., None]  # (B,H,S,1)
+    a = dtt * A.astype(jnp.float32)[None, :, None, None]         # (B,H,S,1)
     y = ssd_scan_bhsp(xt, dtt, a, Bm, Cm, chunk=Q, interpret=interpret)
     return y.transpose(0, 2, 1, 3)

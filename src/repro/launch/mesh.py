@@ -10,16 +10,10 @@ model=16); two pods join over DCN on a leading "pod" axis = (2, 16, 16).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-try:                                    # jax >= 0.5.0 only
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _axis_kw(n_axes: int) -> dict:
-    if AxisType is None:
-        return {}
     return {"axis_types": (AxisType.Auto,) * n_axes}
 
 

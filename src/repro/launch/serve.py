@@ -11,6 +11,7 @@ import time
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config, get_reduced_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.monitoring import MetricsRegistry
 from repro.monitoring.metrics import (
@@ -90,33 +91,30 @@ def parse_buckets(spec: str):
 
 
 def resolve_tp(requested: int, n_devices: int) -> int:
-    """``--tp`` with a graceful fallback: fewer visible devices than the
-    requested shard count downgrades to tp=1 with a warning (and a hint
-    at the forced-host-device recipe) instead of crashing the deploy."""
+    """``--tp N`` needs N visible devices.  Fewer is an error, never a
+    silent tp=1: a deployment sized for N shards would otherwise come up
+    on one chip and report numbers for the wrong system."""
     if requested <= 1:
         return 1
     if n_devices < requested:
-        print(f"[serve] --tp {requested}: only {n_devices} device(s) "
-              f"visible — running tp=1 (set XLA_FLAGS="
-              f"--xla_force_host_platform_device_count={requested} before "
-              f"import to fake devices on CPU)")
-        return 1
+        raise ValueError(
+            f"--tp {requested}: only {n_devices} device(s) visible (on "
+            f"CPU, set XLA_FLAGS=--xla_force_host_platform_device_count="
+            f"{requested} before jax is imported to fake devices)")
     return requested
 
 
 def resolve_use_pallas(requested: bool, backend: str) -> bool:
-    """``--use-pallas`` with a graceful fallback: the split-KV decode
-    kernels are TPU-Pallas, so anywhere else (CPU would run them
-    interpreted — orders of magnitude slower than the jnp reference
-    path; other backends cannot lower them at all) the flag downgrades
-    with a warning instead of tanking the deployment."""
-    if not requested:
-        return False
-    if backend != "tpu":
-        print(f"[serve] --use-pallas: backend is {backend!r}, not TPU — "
-              "falling back to the reference decode path")
-        return False
-    return True
+    """``--use-pallas`` needs a TPU backend: the kernels are TPU-Pallas,
+    and anywhere else they would either run interpreted or not lower at
+    all.  A non-TPU backend is an error, never a silent switch to the
+    reference path."""
+    if requested and backend != "tpu":
+        raise ValueError(
+            f"--use-pallas: backend is {backend!r}, not TPU (the Pallas "
+            "kernels run only on TPU; drop the flag to serve through the "
+            "reference attention path)")
+    return requested
 
 
 def make_requests(args, cfg, names, qos_cycle) -> list:
@@ -155,10 +153,15 @@ def make_requests(args, cfg, names, qos_cycle) -> list:
 
 
 def _serve_elastic(args, cfg, params, metrics, tenants, use_pallas,
-                   kv_paging) -> int:
+                   kv_paging, report) -> int:
     """--replicas/--autoscale: N engines behind the prefix-affinity
     router, optionally as scavenger jobs in a small simulated cluster
-    with the autoscaler driving replica count."""
+    with the autoscaler driving replica count.  Replicas take the visible
+    devices round-robin, each holding its own params and KV pool."""
+    import itertools
+
+    import jax
+
     from repro.cluster.commands import sdiag
     from repro.configs.base import RunConfig
     from repro.serving import Autoscaler, Router
@@ -166,6 +169,9 @@ def _serve_elastic(args, cfg, params, metrics, tenants, use_pallas,
     if args.tp > 1 or args.speculate or args.trace:
         print("[serve] --replicas/--autoscale path ignores --tp, "
               "--speculate and --trace (single-engine features)")
+
+    devices = jax.devices()
+    built = itertools.count()
 
     def make_engine(admission):
         return DecodeEngine(
@@ -176,7 +182,8 @@ def _serve_elastic(args, cfg, params, metrics, tenants, use_pallas,
             prefill_buckets=parse_buckets(args.prefill_buckets),
             kv_page_size=kv_paging, kv_pages=args.kv_pages,
             prefix_cache=args.prefix_cache,
-            max_batch_tokens=args.max_batch_tokens)
+            max_batch_tokens=args.max_batch_tokens,
+            device=devices[next(built) % len(devices)])
 
     router = Router(make_engine,
                     replicas=0 if args.autoscale else args.replicas,
@@ -232,6 +239,7 @@ def _serve_elastic(args, cfg, params, metrics, tenants, use_pallas,
                   f"{router.stats['resubmitted']} request(s) re-routed")
     router.run_to_completion()
     wall = time.perf_counter() - t0
+    report.update(router=router, requests=requests)
     total = int(metrics.counter("serve_tokens_generated").value())
     busy = max(router.busy_seconds().values())
     st = router.stats
@@ -250,13 +258,32 @@ def _serve_elastic(args, cfg, params, metrics, tenants, use_pallas,
               f"{int(metrics.counter(METRIC_SERVE_PREFIX_REUSED_TOKENS).value())} "
               f"prompt tokens reused")
     print(sdiag(cluster=cluster, router=router, autoscaler=autoscaler))
+    return _check_finished(requests)
+
+
+def _check_finished(requests) -> int:
+    """Exit status of a serve run: 0 only when every request finished."""
+    left = [r.rid for r in requests if not r.done]
+    if left:
+        print(f"[serve] {len(left)} request(s) did not finish: {left}")
+        return 1
     return 0
 
 
-def main(argv=None) -> int:
+def main(argv=None, report: dict | None = None) -> int:
+    """Serve the synthetic load; 0 when every request finished.
+
+    ``report``, when given, receives the ``engine`` (or ``router``) and the
+    ``requests`` it served, for callers that embed the server in-process
+    and inspect it afterwards."""
+    report = {} if report is None else report
     ap = argparse.ArgumentParser(prog="repro.launch.serve")
     ap.add_argument("--arch", default="stablelm-3b", choices=ARCH_IDS)
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--param-dtype", default=None,
+                    choices=("float32", "bfloat16"),
+                    help="weight storage dtype (default: the config's); "
+                         "compute runs in the config's dtype either way")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--cache-len", type=int, default=256)
@@ -275,12 +302,11 @@ def main(argv=None) -> int:
                     help="tensor-parallel serving over N devices: params, "
                          "decode dispatches and the paged KV pool shard "
                          "over a (1, N) mesh's 'model' axis; greedy output "
-                         "stays bit-identical to --tp 1 (falls back to 1 "
-                         "when fewer devices are visible)")
+                         "stays bit-identical to --tp 1 (an error when "
+                         "fewer devices are visible)")
     ap.add_argument("--use-pallas", action="store_true",
-                    help="route decode attention through the Pallas "
-                         "split-KV flash-decode kernel (falls back to the "
-                         "reference path on CPU-only backends)")
+                    help="route attention through the Pallas flash "
+                         "kernels (TPU only: an error on other backends)")
     ap.add_argument("--kv-paging", type=int, default=0, metavar="PAGE_SIZE",
                     help="paged KV cache with PAGE_SIZE-line pages "
                          "(0 = dense per-slot cache); short requests then "
@@ -359,12 +385,17 @@ def main(argv=None) -> int:
                          "chrome://tracing); also prints the SLO report")
     args = ap.parse_args(argv)
 
+    import dataclasses
+
     import jax
 
     from repro.configs.base import RunConfig
 
+    enable_compile_cache()
     cfg = (get_reduced_config(args.arch) if args.reduced
            else get_config(args.arch))
+    if args.param_dtype is not None:
+        cfg = dataclasses.replace(cfg, param_dtype=args.param_dtype)
     params = init_params(cfg, args.seed)
     metrics = MetricsRegistry()
     tenants = parse_tenants(args.tenants, args.shares) if args.tenants \
@@ -392,7 +423,7 @@ def main(argv=None) -> int:
             "--draft-model must share the target's vocabulary"
     if args.replicas > 1 or args.autoscale:
         return _serve_elastic(args, cfg, params, metrics, tenants,
-                              use_pallas, kv_paging)
+                              use_pallas, kv_paging, report)
     engine = DecodeEngine(cfg, params, num_slots=args.slots,
                           cache_len=args.cache_len, metrics=metrics,
                           admission=admission,
@@ -424,6 +455,7 @@ def main(argv=None) -> int:
                 engine.step()
     engine.run_to_completion()
     wall = time.perf_counter() - t0
+    report.update(engine=engine, requests=requests)
     total = int(metrics.counter("serve_tokens_generated").value())
     mode = ("host loop" if args.no_fused
             else f"fused chunk={args.decode_chunk}")
@@ -488,7 +520,7 @@ def main(argv=None) -> int:
         print(f"trace: {len(data['traceEvents'])} events -> {args.trace} "
               f"(load in ui.perfetto.dev)")
         print(sdiag(admission=admission, tracer=tracer, engine=engine))
-    return 0
+    return _check_finished(requests)
 
 
 if __name__ == "__main__":

@@ -13,6 +13,7 @@ from repro.configs import (
     get_reduced_config, shape_for,
 )
 from repro.configs.base import InputShape, RunConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.monitoring import MetricsRegistry
 from repro.optim import OptimizerConfig
@@ -42,6 +43,7 @@ def main(argv=None) -> int:
     ap.add_argument("--ckpt-every", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = (get_reduced_config(args.arch) if args.reduced
            else get_config(args.arch))
     base = INPUT_SHAPES[args.shape]

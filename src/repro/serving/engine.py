@@ -196,7 +196,9 @@ class DecodeEngine:
                  spec_source: str = "ngram",
                  draft_model: Optional[ModelConfig] = None,
                  index_generated: Optional[bool] = None,
-                 mesh=None):
+                 mesh=None, device=None):
+        assert mesh is None or device is None, \
+            "a TP engine is placed by its mesh; pass mesh or device"
         self.cfg = cfg
         self.params = params
         self.run = run or RunConfig(remat="none")
@@ -282,6 +284,11 @@ class DecodeEngine:
             self.cache = jax.device_put(
                 self.cache, tp_mod.cache_shardings(self.cache, self.tp,
                                                    cfg))
+        elif device is not None:
+            # one replica per device: committed params and pool pull every
+            # jitted program of this engine onto ``device``
+            self.params = jax.device_put(self.params, device)
+            self.cache = jax.device_put(self.cache, device)
         self.slots: list[Optional[Request]] = [None] * num_slots
         self.pos = np.zeros(num_slots, np.int64)       # next position per slot
         self.last_tok = np.zeros(num_slots, np.int32)

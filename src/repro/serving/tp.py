@@ -45,7 +45,7 @@ from dataclasses import dataclass, field
 from typing import Optional, Sequence
 
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -233,7 +233,7 @@ def wrap(plan: TPPlan, fn, in_specs: Sequence, out_specs,
     prefixes — a bare ``P()`` replicates a whole params/cache subtree).
     The body installs :func:`repro.core.actshard.tp_reduce_scope` so the
     model's ``maybe_psum`` points emit cross-shard reductions exactly
-    where the plan sharded; ``check_rep=False`` because the Pallas
+    where the plan sharded; ``check_vma=False`` because the Pallas
     decode kernels define no replication rules — the ``P()`` out_specs
     are correct by construction (full logits per shard after the psums).
 
@@ -251,7 +251,7 @@ def wrap(plan: TPPlan, fn, in_specs: Sequence, out_specs,
             return fn(*args)
 
     mapped = shard_map(body, mesh=plan.mesh, in_specs=tuple(in_specs),
-                       out_specs=out_specs, check_rep=False)
+                       out_specs=out_specs, check_vma=False)
     if donate:
         return jax.jit(mapped, donate_argnums=donate)
     return jax.jit(mapped)

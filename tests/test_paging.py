@@ -3,7 +3,8 @@ through the engine, the paged Pallas kernel vs its oracle, bucketed-prefill
 x paging interaction (pad tails allocate and charge nothing), page-pool
 exhaustion -> one-victim scavenger reclaim, kv_pages GrpTRES caps and
 ledger residency, plus the sacctmgr modify satellite and the serve CLI's
---use-pallas fallback."""
+device checks (--use-pallas off the TPU, --tp beyond the visible devices)
+and its compile-cache directory."""
 import dataclasses
 
 import jax.numpy as jnp
@@ -423,10 +424,46 @@ def test_kv_pages_cap_is_worst_case_reservation(tiny_model):
 
 # ----------------------------------------------------------- serve CLI ----
 
-def test_use_pallas_falls_back_on_cpu(capsys):
+def test_use_pallas_falls_back_on_cpu():
+    """--use-pallas off the TPU is an error, not a quiet switch to the
+    reference path."""
     from repro.launch.serve import resolve_use_pallas
     assert resolve_use_pallas(False, "cpu") is False
     assert resolve_use_pallas(False, "tpu") is False
     assert resolve_use_pallas(True, "tpu") is True
-    assert resolve_use_pallas(True, "cpu") is False
-    assert "falling back" in capsys.readouterr().out
+    with pytest.raises(ValueError, match="not TPU"):
+        resolve_use_pallas(True, "cpu")
+
+
+def test_tp_needs_enough_devices():
+    """--tp N with fewer than N devices is an error, not a quiet tp=1."""
+    from repro.launch.serve import resolve_tp
+    assert resolve_tp(1, 1) == 1
+    assert resolve_tp(2, 4) == 2
+    with pytest.raises(ValueError, match="only 1 device"):
+        resolve_tp(2, 1)
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """The persistent compile cache goes to $JAX_COMPILATION_CACHE_DIR
+    when set, else to one fixed, git-ignored directory in the checkout."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.launch.compile_cache import (
+        DEFAULT_CACHE_DIR, enable_compile_cache,
+    )
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(DEFAULT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(DEFAULT_CACHE_DIR)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        compilation_cache.reset_cache()
+    root = DEFAULT_CACHE_DIR.parent
+    assert (root / "src" / "repro").is_dir()
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()
