@@ -25,10 +25,19 @@ the per-request story:
   bucket preset (queue wait, TTFT, inter-token latency, end-to-end) plus
   per-tier SLO-attainment counters, the series the ROADMAP's
   SLO-aware-QOS admission will be judged against.
+* **Engine phases on the profiler's clock** — :func:`phase` wraps each
+  host phase of an engine step (``engine.admit``/``plan``/``dispatch``/
+  ``sync``/``commit``) in a ``jax.profiler.TraceAnnotation``, so a
+  profiler trace names the host work behind every device-idle gap; with
+  a tracer attached the same phase is also a span here.
 
-Tracing is strictly opt-in: every producer guards on ``tracer is None``,
-so the untraced hot path pays nothing (``bench_latency_slo`` asserts the
-traced path stays within 5% tok/s of tracing disabled).
+Spans, events and SLO series are opt-in: every producer guards on
+``tracer is None``.  The untraced hot path still pays the phases'
+profiler annotations (no-ops while no profiler runs) and one clock read
+at each request-lifecycle instant and around each step and sync: the
+serving engine keeps a request's queue and prefill waits and its step's
+host time as plain numbers (:func:`stamp`; ``bench_latency_slo`` asserts
+the traced path stays within 5% tok/s of tracing disabled).
 """
 from __future__ import annotations
 
@@ -46,7 +55,7 @@ from repro.monitoring.metrics import LATENCY_BUCKETS, MetricsRegistry
 # Derived SLO series (latency-tuned buckets, labeled {tenant=, qos=}).
 #: seconds between enqueue and admission pick
 METRIC_SERVE_QUEUE_WAIT = "serve_queue_wait_seconds"
-#: admit -> first decoded token
+#: submit -> first decoded token (queue wait included)
 METRIC_SERVE_TTFT = "serve_ttft_seconds"
 #: per-token inter-token latency (chunk-amortized on the fused path)
 METRIC_SERVE_ITL = "serve_itl_seconds"
@@ -61,6 +70,8 @@ METRIC_SLO_ITL_VIOLATIONS = "serve_slo_itl_violations"
 
 #: default (process, thread) lane for spans that don't name one
 DEFAULT_TRACK = ("trace", "main")
+#: the lane of the serving engine's step phases
+ENGINE_TRACK = ("serving:engine", "step")
 
 
 @dataclass
@@ -133,8 +144,10 @@ class SLORecorder:
             seconds, tenant=tenant, qos=qos)
 
     def ttft(self, seconds: float, tenant: str, qos: str):
+        """Submission -> first token: what the user waits, queue
+        included."""
         self._hist(METRIC_SERVE_TTFT,
-                   "admit -> first decoded token").observe(
+                   "submit -> first decoded token").observe(
             seconds, tenant=tenant, qos=qos)
         self._attain(seconds, self.targets.get(qos, SLOTarget()).ttft_s,
                      METRIC_SLO_TTFT_MET, METRIC_SLO_TTFT_VIOLATIONS,
@@ -143,25 +156,25 @@ class SLORecorder:
     def itl(self, seconds: float, tenant: str, qos: str, n: int = 1):
         """Per-token inter-token latency.  The fused decode path syncs
         once per chunk, so it reports the chunk-amortized per-token
-        latency ``n`` times — the histogram stays token-weighted."""
-        hist = self._hist(METRIC_SERVE_ITL, "per-token inter-token latency")
-        for _ in range(n):
-            hist.observe(seconds, tenant=tenant, qos=qos)
-        target = self.targets.get(qos, SLOTarget()).itl_s
-        for _ in range(n):
-            self._attain(seconds, target, METRIC_SLO_ITL_MET,
-                         METRIC_SLO_ITL_VIOLATIONS, tenant, qos)
+        latency as one observation of weight ``n`` — the histogram and
+        the attainment counters stay token-weighted."""
+        self._hist(METRIC_SERVE_ITL, "per-token inter-token latency").observe(
+            seconds, n=n, tenant=tenant, qos=qos)
+        self._attain(seconds, self.targets.get(qos, SLOTarget()).itl_s,
+                     METRIC_SLO_ITL_MET, METRIC_SLO_ITL_VIOLATIONS,
+                     tenant, qos, n)
 
     def e2e(self, seconds: float, tenant: str, qos: str):
         self._hist(METRIC_SERVE_E2E, "submit -> finish").observe(
             seconds, tenant=tenant, qos=qos)
 
-    def _attain(self, seconds, target, met_name, viol_name, tenant, qos):
+    def _attain(self, seconds, target, met_name, viol_name, tenant, qos,
+                n: int = 1):
         if target is None:
             return
         name = met_name if seconds <= target else viol_name
         self.metrics.counter(name, "SLO attainment").inc(
-            tenant=tenant, qos=qos)
+            n, tenant=tenant, qos=qos)
 
     # ----------------------------------------------------------- reports ----
     def attainment(self) -> dict[tuple, dict]:
@@ -360,3 +373,44 @@ class Tracer:
             with open(path, "w") as f:
                 json.dump(data, f)
         return data
+
+
+def stamp(tracer: Optional["Tracer"] = None) -> float:
+    """The time of one request-lifecycle instant (submit, enqueue,
+    admission, first token), read once: on the tracer's clock, so its
+    spans and SLO series reuse the same stamp, or ``time.perf_counter``
+    with no tracer."""
+    return tracer.clock() if tracer is not None else time.perf_counter()
+
+
+class _Phase:
+    """A :func:`phase` with a tracer: the profiler annotation around a
+    tracer span of the same name on :data:`ENGINE_TRACK`."""
+    __slots__ = ("name", "tracer", "annotation", "span")
+
+    def __init__(self, name: str, tracer: Tracer, annotation):
+        self.name, self.tracer, self.annotation = name, tracer, annotation
+
+    def __enter__(self):
+        self.annotation.__enter__()
+        self.span = self.tracer.begin(self.name, cat="engine",
+                                      track=ENGINE_TRACK)
+        return self.span
+
+    def __exit__(self, *exc):
+        self.tracer.end(self.span)
+        return self.annotation.__exit__(*exc)
+
+
+def phase(name: str, tracer: Optional[Tracer] = None):
+    """``with phase("engine.sync", tracer):`` — one host phase of an
+    engine step.  Always a ``jax.profiler.TraceAnnotation`` (host spans in
+    a profiler trace share the device operations' clock, so device-idle
+    time can be attributed to the phase that covers it); with a tracer
+    attached, also a span of the same name on the engine's track.  With
+    no profiler running and no tracer, the cost is one annotation object:
+    ``name`` is the caller's constant, and nothing else is built."""
+    from jax.profiler import TraceAnnotation   # the cluster sim needs no jax
+    if tracer is None:
+        return TraceAnnotation(name)
+    return _Phase(name, tracer, TraceAnnotation(name))

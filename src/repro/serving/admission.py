@@ -49,6 +49,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro.monitoring.trace import stamp
 from repro.policy import (
     FairShareTree, PriorityWeights, QOS, default_qos_table, tres_within,
 )
@@ -197,9 +198,11 @@ class AdmissionController:
 
     # ----------------------------------------------------------- tracing ----
     def _trace_enqueue(self, req, resumed: bool = False):
-        """Open a QUEUED span for a (re)enqueued request: closed by the
-        pick that admits it, its duration IS the queue wait."""
+        """Stamp the start of a queue wait on the request and open a
+        QUEUED span at that stamp for a (re)enqueued request: closed by
+        the pick that admits it, its duration IS the queue wait."""
         tr = self.tracer
+        req.queued_at = stamp(tr)
         if tr is None:
             return
         trace = getattr(req, "_trace", None)
@@ -209,26 +212,29 @@ class AdmissionController:
         track = root.track if root is not None else (
             f"serving:{req.tenant}", f"req {getattr(req, 'rid', '?')}")
         trace["queued"] = tr.begin("QUEUED", cat="queue", track=track,
-                                   parent=root, resumed=resumed,
-                                   qos=req.qos)
+                                   parent=root, ts=req.queued_at,
+                                   resumed=resumed, qos=req.qos)
 
     def _trace_pick(self, req, reason: str):
-        """Close the QUEUED span with the pick reason and feed the
-        queue-wait SLO series (admit timestamp stamps the request — the
-        engine's TTFT measurement starts here)."""
+        """End the queue wait: until the request's first token it adds to
+        ``req.queue_s``, and ``req.admitted_at`` stamps the admission.
+        Then close the QUEUED span with the pick reason and feed the
+        queue-wait SLO series."""
         self.stats["picks"] += 1
         if reason == "preemption":
             self.stats["preempt_picks"] += 1
         tr = self.tracer
+        picked = stamp(tr)
+        if req.prefill_s is None:
+            req.queue_s += picked - req.queued_at
+        req.queued_at, req.admitted_at = None, picked
         if tr is None:
             return
-        now = tr.clock()
-        req._t_admit = now
         trace = getattr(req, "_trace", None)
         queued = trace.pop("queued", None) if trace else None
         if queued is not None:
-            wait = now - queued.start
-            tr.end(queued, ts=now, pick_reason=reason,
+            wait = picked - queued.start
+            tr.end(queued, ts=picked, pick_reason=reason,
                    fairshare=round(
                        self.tree.fair_share_factor(req.tenant), 4))
         else:

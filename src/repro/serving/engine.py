@@ -87,6 +87,7 @@ serve-step utilization section.
 from __future__ import annotations
 
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Optional, Sequence, Union
 
@@ -105,6 +106,7 @@ from repro.models.paging import (
     TwoLevelPageTable, pages_for,
 )
 from repro.monitoring import MetricsRegistry, Tracer
+from repro.monitoring.trace import phase, stamp
 from repro.monitoring.metrics import (
     METRIC_SERVE_KV_PAGES_IN_USE, METRIC_SERVE_PREEMPTIONS,
     METRIC_SERVE_PREFIX_EVICTIONS, METRIC_SERVE_PREFIX_HITS,
@@ -136,6 +138,15 @@ class Request:
     output: list = field(default_factory=list)
     done: bool = False
     preemptions: int = 0               # times evicted mid-decode
+    # the wait for the first token: seconds queued before it (every
+    # queue wait until then), and from the admission that produced it to
+    # it; then the stamps they come from, one per instant
+    # (monitoring.trace.stamp: the tracer's clock, else perf_counter)
+    queue_s: float = 0.0
+    prefill_s: Optional[float] = None
+    submitted_at: Optional[float] = None
+    queued_at: Optional[float] = None     # None while not queued
+    admitted_at: Optional[float] = None   # the latest admission
     _seq: int = field(default=0, repr=False)   # admission arrival order
     _slot: int = field(default=-1, repr=False)  # current decode slot (-1 = none)
     _est_pages: int = field(default=0, repr=False)  # paged: worst-case pages
@@ -144,18 +155,17 @@ class Request:
     _held_pages: int = field(default=0, repr=False)
     # lifecycle tracing (populated only when the engine has a tracer)
     _trace: dict = field(default_factory=dict, repr=False)  # open spans
-    _t_submit: Optional[float] = field(default=None, repr=False)
-    _t_admit: Optional[float] = field(default=None, repr=False)
     _t_last: Optional[float] = field(default=None, repr=False)  # last token
 
 
-@dataclass
+@dataclass(eq=False)
 class _PartialPrefill:
     """An admitted request whose prompt is not fully prefilled yet.  It
     owns a decode slot (so eviction/requeue ride the existing paths) but
     its decode lane stays frozen — the page-table row the decode dispatch
     sees is all-NULL until promotion — while ``pos_filled`` advances one
-    chunk at a time across engine iterations."""
+    chunk at a time across engine iterations.  Compared by identity: two
+    partials are never the same entry of ``_partials`` by value."""
     req: Request
     toks: np.ndarray                    # full resume/prefill token sequence
     slot: int
@@ -164,6 +174,7 @@ class _PartialPrefill:
     n_shared: int = 0                   # leading prefix-cache pages
     chunks: int = 0                     # chunks dispatched so far
     span: object = None                 # open PREFILL trace span
+    admitted_iteration: int = 0         # serve_stats iteration admitting it
 
 
 @dataclass
@@ -306,9 +317,13 @@ class DecodeEngine:
         # step paths need no mode guards
         self._partials: list[_PartialPrefill] = []
         self._prefill_slots: dict[int, _PartialPrefill] = {}
-        #: per-iteration counters behind sdiag's serve-step utilization
+        #: per-iteration counters behind sdiag's serve-step utilization;
+        #: ``iterations`` counts every ``step``, ``host_s`` its time
+        #: outside its ``engine.sync`` phases
         self.serve_stats = {"iterations": 0, "decode_tokens": 0,
-                            "prefill_tokens": 0, "prefill_chunks": 0}
+                            "prefill_tokens": 0, "prefill_chunks": 0,
+                            "host_s": 0.0}
+        self._synced = 0.0              # seconds in engine.sync so far
         self.max_batch_tokens: Optional[int] = None
         self._mixed_step = None
         if max_batch_tokens is not None:
@@ -449,6 +464,8 @@ class DecodeEngine:
         return self._ptab.dense(self._table_width)
 
     # ------------------------------------------------------------ jitted ----
+    # Each builder jits a function whose name is unique in the engine: a
+    # profiler trace names the program after it (``jit_<name>``).
     def _tp_wrap(self, fn, in_kinds: str, out_kinds: str,
                  donate: tuple = ()):
         """``tp.wrap`` with per-argument specs named by kind: ``p`` the
@@ -659,8 +676,9 @@ class DecodeEngine:
         role."""
         cfg, run = self.cfg, self.run
 
-        def mixed(params, cache, tokens, pos0, pages, offs, page_table,
-                  c_tokens, c_row, c_start, c_last, c_pages, c_offs):
+        def mixed_verify(params, cache, tokens, pos0, pages, offs,
+                         page_table, c_tokens, c_row, c_start, c_last,
+                         c_pages, c_offs):
             c_logits, c_slices = prefill_chunk(
                 params, {"tokens": c_tokens}, cache, c_row, c_start, cfg,
                 run, last_pos=c_last)
@@ -672,7 +690,8 @@ class DecodeEngine:
             return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
                     logits, cache, c_logits)
 
-        return self._tp_wrap(mixed, "pc" + "r" * 11, "rrcr", donate=(1,))
+        return self._tp_wrap(mixed_verify, "pc" + "r" * 11, "rrcr",
+                             donate=(1,))
 
     def _resolve_buckets(self, spec):
         """Power-of-two prompt-length buckets, or None (exact-length
@@ -761,6 +780,23 @@ class DecodeEngine:
             g.set(int(n), device=str(dev))
 
     # ----------------------------------------------------------- tracing ----
+    @contextmanager
+    def _sync(self):
+        """A blocking device->host read: the ``engine.sync`` phase, timed
+        so that ``step`` can leave it out of ``host_s``."""
+        t0 = time.perf_counter()
+        with phase("engine.sync", self.tracer):
+            yield
+        self._synced += time.perf_counter() - t0
+
+    def _token_known(self, req: Request, resume: bool) -> float:
+        """Stamp the instant a prefill's token is known; a first token
+        (not a resume's) ends the request's prefill wait."""
+        now = stamp(self.tracer)
+        if not resume:
+            req.prefill_s = now - req.admitted_at
+        return now
+
     def _trace_root(self, req: Request):
         trace = getattr(req, "_trace", None)
         return trace.get("root") if trace else None
@@ -790,16 +826,16 @@ class DecodeEngine:
         # (prompt + partial output) still fits the cache
         assert len(req.prompt) < self.cache_len, "prompt exceeds cache"
         tr = self.tracer
+        req.submitted_at = stamp(tr)
         if tr is not None:
-            req._t_submit = tr.clock()
             root = tr.begin(
                 f"request {req.rid}", cat="request",
                 track=(f"serving:{req.tenant}", f"req {req.rid}"),
-                rid=req.rid, tenant=req.tenant, qos=req.qos,
-                prompt_tokens=len(req.prompt),
+                ts=req.submitted_at, rid=req.rid, tenant=req.tenant,
+                qos=req.qos, prompt_tokens=len(req.prompt),
                 max_new_tokens=req.max_new_tokens)
             req._trace = {"root": root}
-            tr.event("SUBMIT", root)
+            tr.event("SUBMIT", root, ts=req.submitted_at)
         if self.paging is not None:
             # worst-case page footprint, for GrpTRES kv_pages caps
             req._est_pages = pages_for(
@@ -960,7 +996,8 @@ class DecodeEngine:
             if root is not None:
                 tr.event("ADMIT", root, slot=slot)
             psp = tr.begin("PREFILL", cat="prefill", parent=root,
-                           tokens=len(toks), resume=resume)
+                           ts=req.admitted_at, tokens=len(toks),
+                           resume=resume)
         pages = shared = None
         if self.paging is not None:
             ps = self.paging.page_size
@@ -1006,7 +1043,9 @@ class DecodeEngine:
                     self.metrics.counter(
                         METRIC_SERVE_PREFIX_MISSES,
                         "admissions with no cached prefix").inc()
-        with self.metrics.timer("serve_prefill_seconds", "prefill latency"):
+        # the sync nests in the dispatch phase, which owns the rest
+        with self.metrics.timer("serve_prefill_seconds", "prefill latency"), \
+                phase("engine.dispatch", tr):
             if shared:
                 # prefix hit: prefill ONLY the suffix, attending to the
                 # shared pages through a prefix-only page-table row.  The
@@ -1061,7 +1100,8 @@ class DecodeEngine:
             # sync inside the timed region: dispatch is async, and the
             # very next consumer (argmax below) would absorb the device
             # wait — serve_prefill_seconds must report real latency
-            jax.block_until_ready(logits)
+            with self._sync():
+                jax.block_until_ready(logits)
         if self.paging is not None:
             # scatter the prefilled lines into the privately-owned pages
             # (suffix-only on a prefix hit — shared pages are READ-ONLY
@@ -1070,8 +1110,9 @@ class DecodeEngine:
             ps = self.paging.page_size
             page_ids = np.full(pages_for(L, ps), NULL_PAGE, np.int32)
             page_ids[:len(priv)] = priv
-            self.cache = self._insert(self.cache, cache1,
-                                      jnp.asarray(page_ids))
+            with phase("engine.dispatch", tr):
+                self.cache = self._insert(self.cache, cache1,
+                                          jnp.asarray(page_ids))
             self._ptab.clear(slot)
             self._ptab.set_range(slot, 0, pages)
             self._slot_pages[slot] = pages
@@ -1090,12 +1131,15 @@ class DecodeEngine:
         else:
             # write this request's cache slice into the batch cache through
             # the pre-jitted donated insert (one compile, zero retraces)
-            self.cache = self._insert(self.cache, cache1, slot)
+            with phase("engine.dispatch", tr):
+                self.cache = self._insert(self.cache, cache1, slot)
         if req.output:
             tok = int(req.output[-1])      # resume: last token re-decodes
         else:
-            tok = int(jnp.argmax(logits[0, -1]))
+            with self._sync():
+                tok = int(jnp.argmax(logits[0, -1]))
             req.output.append(tok)
+        now = self._token_known(req, resume)
         self.slots[slot] = req
         req._slot = slot
         self.pos[slot] = len(toks)
@@ -1117,8 +1161,7 @@ class DecodeEngine:
             METRIC_SERVE_TENANT_ADMITTED,
             "admissions per tenant").inc(tenant=req.tenant)
         if tr is not None:
-            now = tr.clock()
-            attrs = {"bucket": int(L)}
+            attrs = {"bucket": int(L), "chunks": 1, "iterations": 1}
             if self.paging is not None:
                 n_sh = len(shared) if shared else 0
                 attrs.update(prefix_pages=n_sh,
@@ -1129,15 +1172,15 @@ class DecodeEngine:
                     tr.event("RESUME", root, slot=slot)
             else:
                 # the first output token comes from the prefill argmax,
-                # so TTFT = admit -> end of the prefill sync (resumes
+                # so TTFT = submit -> end of the prefill sync (resumes
                 # already produced their first token pre-eviction)
                 if root is not None:
                     tr.event("first_token", root)
-                if req._t_admit is not None:
-                    tr.slo.ttft(now - req._t_admit, req.tenant, req.qos)
+                if req.submitted_at is not None:
+                    tr.slo.ttft(now - req.submitted_at, req.tenant, req.qos)
             req._t_last = now
             req._trace["decode"] = tr.begin(
-                "DECODE", cat="decode", parent=root, slot=slot)
+                "DECODE", cat="decode", parent=root, ts=now, slot=slot)
         self._maybe_finish(slot)
 
     def _hold_pages(self, req: Request, delta: int):
@@ -1247,8 +1290,9 @@ class DecodeEngine:
             if root is not None:
                 tr.event("FINISH", root)
                 tr.end(root, tokens=len(req.output))
-            if req._t_submit is not None:
-                tr.slo.e2e(tr.clock() - req._t_submit, req.tenant, req.qos)
+            if req.submitted_at is not None:
+                tr.slo.e2e(tr.clock() - req.submitted_at, req.tenant,
+                           req.qos)
 
     def _maybe_finish(self, slot: int):
         req = self.slots[slot]
@@ -1371,8 +1415,8 @@ class DecodeEngine:
             if root is not None:
                 tr.event("ADMIT", root, slot=slot)
             span = tr.begin("PREFILL", cat="prefill", parent=root,
-                            tokens=len(toks), resume=bool(req.output),
-                            chunked=True)
+                            ts=req.admitted_at, tokens=len(toks),
+                            resume=bool(req.output), chunked=True)
         shared = []
         if self.prefix is not None:
             shared = self.prefix.acquire(self.prefix.match(toks)) or []
@@ -1404,7 +1448,8 @@ class DecodeEngine:
         part = _PartialPrefill(
             req=req, toks=toks, slot=slot,
             pos_filled=len(shared) * self.paging.page_size,
-            pages=list(shared), n_shared=len(shared), span=span)
+            pages=list(shared), n_shared=len(shared), span=span,
+            admitted_iteration=self.serve_stats["iterations"])
         self._hold_pages(req, len(shared))
         self._partials.append(part)
         self._prefill_slots[slot] = part
@@ -1489,7 +1534,8 @@ class DecodeEngine:
         chunk.  The prefill histogram therefore times submission here —
         device time shows up in the PREFILL trace span (admit ->
         promotion)."""
-        with self.metrics.timer("serve_prefill_seconds", "prefill latency"):
+        with phase("engine.dispatch", self.tracer), self.metrics.timer(
+                "serve_prefill_seconds", "prefill latency"):
             logits, self.cache = self._chunk_fn(
                 self.params, self.cache, jnp.asarray(plan.tokens)[None],
                 jnp.asarray(plan.row)[None],
@@ -1504,25 +1550,23 @@ class DecodeEngine:
         promotes the request to a live decode lane (its first output
         token is this chunk's last-position argmax — exactly the
         whole-prompt prefill's)."""
-        part = plan.part
-        part.pos_filled = plan.start + plan.real
-        part.chunks += 1
-        self.serve_stats["prefill_tokens"] += plan.real
-        self.serve_stats["prefill_chunks"] += 1
-        if part.pos_filled >= len(part.toks):
-            self._partials.remove(part)
-            self._promote(part, logits)
+        with phase("engine.commit", self.tracer):
+            part = plan.part
+            part.pos_filled = plan.start + plan.real
+            part.chunks += 1
+            self.serve_stats["prefill_tokens"] += plan.real
+            self.serve_stats["prefill_chunks"] += 1
+            if part.pos_filled >= len(part.toks):
+                self._partials.remove(part)
+                self._promote(part, logits)
 
     def _promote(self, part: _PartialPrefill, logits):
-        """Last chunk done: unfreeze the slot into a decode lane."""
+        """Last chunk done: unfreeze the slot into a decode lane.  The
+        PREFILL span ends once the first token is known, ``iterations``
+        engine iterations after admission (1: the admitting one)."""
         req, slot = part.req, part.slot
         tr = self.tracer
         root = self._trace_root(req)
-        if part.span is not None:
-            now = tr.clock()
-            tr.end(part.span, ts=now, chunks=part.chunks,
-                   pos_filled=part.pos_filled, prefix_pages=part.n_shared,
-                   pages_allocated=len(part.pages) - part.n_shared)
         self._ptab.clear(slot)
         self._ptab.set_range(slot, 0, part.pages)
         self._slot_pages[slot] = part.pages
@@ -1531,11 +1575,15 @@ class DecodeEngine:
             # holder refs were registered page-by-page as chunks grew
             self.prefix.insert(part.toks, part.pages)
         resume = bool(req.output)
+        iterations = (self.serve_stats["iterations"]
+                      - part.admitted_iteration + 1)
         if resume:
             tok = int(req.output[-1])      # resume: last token re-decodes
         else:
-            tok = int(jnp.argmax(logits[0, -1]))
+            with self._sync():
+                tok = int(jnp.argmax(logits[0, -1]))
             req.output.append(tok)
+        now = self._token_known(req, resume)
         self.pos[slot] = len(part.toks)
         self.last_tok[slot] = tok
         self.remaining[slot] = req.max_new_tokens - len(req.output)
@@ -1547,21 +1595,25 @@ class DecodeEngine:
             METRIC_SERVE_TENANT_ADMITTED,
             "admissions per tenant").inc(tenant=req.tenant)
         if tr is not None:
-            now = tr.clock()
+            if part.span is not None:
+                tr.end(part.span, ts=now, chunks=part.chunks,
+                       iterations=iterations, pos_filled=part.pos_filled,
+                       prefix_pages=part.n_shared,
+                       pages_allocated=len(part.pages) - part.n_shared)
             if resume:
                 if root is not None:
                     tr.event("RESUME", root, slot=slot)
             else:
                 # first output token came from the final chunk's argmax:
-                # TTFT = admit -> that chunk's sync (resumes produced
+                # TTFT = submit -> that chunk's sync (resumes produced
                 # their first token pre-eviction)
                 if root is not None:
                     tr.event("first_token", root)
-                if req._t_admit is not None:
-                    tr.slo.ttft(now - req._t_admit, req.tenant, req.qos)
+                if req.submitted_at is not None:
+                    tr.slo.ttft(now - req.submitted_at, req.tenant, req.qos)
             req._t_last = now
             req._trace["decode"] = tr.begin(
-                "DECODE", cat="decode", parent=root, slot=slot)
+                "DECODE", cat="decode", parent=root, ts=now, slot=slot)
         del self._prefill_slots[slot]
         self._maybe_finish(slot)
 
@@ -1610,36 +1662,37 @@ class DecodeEngine:
         same dispatch as decode, any leftover budget drained through
         standalone chunk dispatches."""
         st = self.serve_stats
-        st["iterations"] += 1
         T = self.max_batch_tokens
-        decode_active = self._decode_active()
-        d = self.decode_chunk
-        # speculative lanes cost k+1 budget tokens each (worst case: all
-        # drafts accepted plus the bonus); if that starves pending
-        # prefills entirely, drop to the plain 1-token lane mix instead
-        spec = self.speculate > 0
-        lane = (self.speculate + 1) if spec else d
-        if (self._partials and decode_active
-                and lane * len(decode_active) > T):
-            d, spec, lane = 1, False, 1
-        if decode_active:
-            self._ensure_pages(decode_active, steps=lane)
+        tr = self.tracer
+        with phase("engine.plan", tr):
             decode_active = self._decode_active()
-        budget = T
-        head_plan = None
-        if self._partials and decode_active:
-            budget -= lane * len(decode_active)
-            for part in self._pack_order():
-                if budget < 1:
-                    break
-                if self._prefill_slots.get(part.slot) is not part:
-                    continue            # starved away by an earlier plan
-                head_plan = self._plan_chunk(part, budget)
-                if head_plan is not None:
-                    budget -= head_plan.bucket
-                    break
-            # planning may have reclaim-evicted a decode slot
-            decode_active = self._decode_active()
+            d = self.decode_chunk
+            # speculative lanes cost k+1 budget tokens each (worst case:
+            # all drafts accepted plus the bonus); if that starves pending
+            # prefills entirely, drop to the plain 1-token lane mix
+            spec = self.speculate > 0
+            lane = (self.speculate + 1) if spec else d
+            if (self._partials and decode_active
+                    and lane * len(decode_active) > T):
+                d, spec, lane = 1, False, 1
+            if decode_active:
+                self._ensure_pages(decode_active, steps=lane)
+                decode_active = self._decode_active()
+            budget = T
+            head_plan = None
+            if self._partials and decode_active:
+                budget -= lane * len(decode_active)
+                for part in self._pack_order():
+                    if budget < 1:
+                        break
+                    if self._prefill_slots.get(part.slot) is not part:
+                        continue        # starved away by an earlier plan
+                    head_plan = self._plan_chunk(part, budget)
+                    if head_plan is not None:
+                        budget -= head_plan.bucket
+                        break
+                # planning may have reclaim-evicted a decode slot
+                decode_active = self._decode_active()
         if decode_active:
             if spec:
                 total, chunk_out = self._step_spec(decode_active,
@@ -1674,13 +1727,16 @@ class DecodeEngine:
         min_bucket = max(1, self.chunk_buckets[-1] // 4)
         while budget >= 1 and self._partials:
             progressed = False
-            for part in self._pack_order():
+            with phase("engine.plan", tr):
+                order = self._pack_order()
+            for part in order:
                 if budget < 1:
                     break
                 if self._prefill_slots.get(part.slot) is not part:
                     continue
-                plan = self._plan_chunk(part, budget,
-                                        min_bucket=min_bucket)
+                with phase("engine.plan", tr):
+                    plan = self._plan_chunk(part, budget,
+                                            min_bucket=min_bucket)
                 if plan is None:
                     continue
                 self._finish_chunk(plan, self._dispatch_chunk(plan))
@@ -1698,23 +1754,40 @@ class DecodeEngine:
 
         Budgeted mode (``max_batch_tokens``) runs the token-budgeted
         continuous-batching iteration instead: decode lanes plus packed
-        prefill chunks under one budget."""
-        self._admit()
-        self._update_pool_gauges()
+        prefill chunks under one budget.
+
+        Every host instant of a step lies in one ``engine.*`` phase
+        (``monitoring.trace.phase``): admit, plan, dispatch, sync, commit.
+        ``serve_stats["host_s"]`` keeps the step's time outside its syncs.
+        """
+        st = self.serve_stats
+        t0, sync0 = time.perf_counter(), self._synced
+        st["iterations"] += 1
+        left = self._iterate()
+        st["host_s"] += time.perf_counter() - t0 - (self._synced - sync0)
+        return left
+
+    def _iterate(self) -> int:
+        tr = self.tracer
+        with phase("engine.admit", tr):
+            self._admit()
+            self._update_pool_gauges()
         if self.max_batch_tokens is not None:
             return self._step_budgeted()
-        active = [i for i, r in enumerate(self.slots) if r is not None]
-        if self.paging is not None and active:
-            # speculative rounds may advance a lane by up to k+1 AND fall
-            # back to the plain fused chunk when no lane has drafts —
-            # pre-grow pages for whichever path runs
-            self._ensure_pages(
-                active, steps=(max(self.decode_chunk, self.speculate + 1)
-                               if self.speculate else None))
-            # growth may have evicted/requeued slots at ANY index (a
-            # reclaim victim can precede its requester) — rebuild rather
-            # than trust the in-place edits
+        with phase("engine.plan", tr):
             active = [i for i, r in enumerate(self.slots) if r is not None]
+            if self.paging is not None and active:
+                # speculative rounds may advance a lane by up to k+1 AND
+                # fall back to the plain fused chunk when no lane has
+                # drafts — pre-grow pages for whichever path runs
+                self._ensure_pages(
+                    active, steps=(max(self.decode_chunk, self.speculate + 1)
+                                   if self.speculate else None))
+                # growth may have evicted/requeued slots at ANY index (a
+                # reclaim victim can precede its requester) — rebuild
+                # rather than trust the in-place edits
+                active = [i for i, r in enumerate(self.slots)
+                          if r is not None]
         if not active:
             return self.admission.pending()
         if self.speculate:
@@ -1735,6 +1808,22 @@ class DecodeEngine:
         into the SAME dispatch.  Mid-prefill slots count as done (their
         lanes freeze; capacity 0 routes their writes to the null page).
         Returns ``(generated_tokens, chunk_outputs_or_None)``."""
+        tr = self.tracer
+        with phase("engine.dispatch", tr):
+            t0, out, chunk_out = self._dispatch_fused(num_tokens, chunk_plan)
+        # ONE sync per chunk: everything after it is host-side numpy
+        with self._sync():
+            out = [np.asarray(x) for x in out]
+        with phase("engine.commit", tr):
+            total = self._commit_fused(active, *out, t0)
+        return total, chunk_out
+
+    def _dispatch_fused(self, num_tokens: Optional[int],
+                        chunk_plan: Optional[_ChunkPlan]):
+        """Stage the lanes' arguments and dispatch the decode program
+        (fused with ``chunk_plan`` when given).  Returns the dispatch
+        time, the lane outputs to sync (tokens, pos, last token,
+        remaining, done) and the chunk's outputs."""
         done = np.array([self.slots[i] is None or i in self._prefill_slots
                          for i in range(self.num_slots)])
         eos = np.array([
@@ -1744,10 +1833,6 @@ class DecodeEngine:
         temps = np.array([
             (self.slots[i].temperature if self.slots[i] else 0.0)
             for i in range(self.num_slots)], np.float32)
-        tr = self.tracer
-        csp = tr.begin("decode_chunk", cat="engine",
-                       track=("serving:engine", "dispatch"),
-                       active=len(active)) if tr is not None else None
         t0 = time.perf_counter()
         chunk_out = None
         if self.paging is not None:
@@ -1793,12 +1878,13 @@ class DecodeEngine:
                     jnp.asarray(self.remaining.astype(np.int32)),
                     jnp.asarray(done), jnp.asarray(eos), jnp.asarray(temps),
                     self._key)
-        # ONE sync per chunk: everything below is host-side numpy
-        toks = np.asarray(toks)
-        pos = np.asarray(pos)
-        token = np.asarray(token)
-        remaining = np.asarray(remaining)
-        done_d = np.asarray(done_d)
+        return t0, (toks, pos, token, remaining, done_d), chunk_out
+
+    def _commit_fused(self, active: list, toks, pos, token, remaining,
+                      done_d, t0: float) -> int:
+        """Per-lane outputs, billing, finish and requeue after a fused
+        dispatch's sync.  Returns the tokens generated."""
+        tr = self.tracer
         self.metrics.histogram("serve_decode_seconds",
                                "batched decode-step latency").observe(
             time.perf_counter() - t0)
@@ -1853,15 +1939,13 @@ class DecodeEngine:
                     self._requeue_starved(i)
                 else:
                     self._finish(i)
-        if csp is not None:
-            tr.end(csp, ts=ts_sync, tokens=total)
         self.admission.charge_bulk(charges)
         self.metrics.counter("serve_tokens_generated").inc(total)
         tok_counter = self.metrics.counter(
             METRIC_SERVE_TENANT_TOKENS, "generated tokens per tenant")
         for tenant, n in tenant_tokens.items():
             tok_counter.inc(n, tenant=tenant)
-        return total, chunk_out
+        return total
 
     def _step_spec(self, active: list,
                    chunk_plan: Optional[_ChunkPlan] = None):
@@ -1885,25 +1969,46 @@ class DecodeEngine:
         Returns ``(generated_tokens, chunk_outputs_or_None)`` like
         ``_step_fused``."""
         k = self.speculate
-        S = k + 1
-        ps = self.paging.page_size
-        drafts: dict[int, np.ndarray] = {}
-        kinds: dict[int, str] = {}
-        for i in active:
-            d = np.asarray(self.spec.draft(i, k), np.int32).ravel()[:k]
-            kinds[i] = getattr(self.spec, "last_kind", self.spec.kind)
-            # drop drafts the lane has no room to verify: every USED
-            # verify row must read only lines inside the allocation
-            room = self._capacity(i) - 1 - int(self.pos[i])
-            if len(d) > room:
-                d = d[:max(room, 0)]
-            drafts[i] = d
+        tr = self.tracer
+        with phase("engine.plan", tr):
+            drafts: dict[int, np.ndarray] = {}
+            kinds: dict[int, str] = {}
+            for i in active:
+                d = np.asarray(self.spec.draft(i, k), np.int32).ravel()[:k]
+                kinds[i] = getattr(self.spec, "last_kind", self.spec.kind)
+                # drop drafts the lane has no room to verify: every USED
+                # verify row must read only lines inside the allocation
+                room = self._capacity(i) - 1 - int(self.pos[i])
+                if len(d) > room:
+                    d = d[:max(room, 0)]
+                drafts[i] = d
         if chunk_plan is None and all(len(drafts[i]) == 0 for i in active):
             if self.max_batch_tokens is not None:
                 return self._step_fused(active, num_tokens=1)
             return self._step_fused(active)
-        st = self.spec_stats
-        proposed = sum(len(drafts[i]) for i in active)
+        with phase("engine.dispatch", tr):
+            t0, greedy, logits, chunk_out = self._dispatch_spec(
+                active, drafts, chunk_plan)
+        # ONE host sync per round; raw logits transfer only under
+        # temperature (greedy needs just the device argmax)
+        with self._sync():
+            greedy = np.asarray(greedy)
+            logits_np = (np.asarray(logits).astype(np.float32)
+                         if any(self.slots[i].temperature > 0
+                                for i in active) else None)
+        with phase("engine.commit", tr):
+            total = self._commit_spec(active, drafts, kinds, greedy,
+                                      logits_np, t0)
+        return total, chunk_out
+
+    def _dispatch_spec(self, active: list, drafts: dict,
+                       chunk_plan: Optional[_ChunkPlan]):
+        """Stage every lane's last token and drafts with their KV-line
+        targets, and dispatch the verify program (fused with
+        ``chunk_plan`` when given).  Returns the dispatch time, the
+        device argmax and logits, and the chunk's outputs."""
+        S = self.speculate + 1
+        ps = self.paging.page_size
         tokens = np.zeros((self.num_slots, S), np.int32)
         pos0 = np.zeros(self.num_slots, np.int32)
         pages = np.full((self.num_slots, S), NULL_PAGE, np.int32)
@@ -1922,12 +2027,6 @@ class DecodeEngine:
                     offs[i, j] = (p0 + j) % ps
             # columns past the allocation (and every column of frozen /
             # empty / mid-prefill lanes) scatter to the null page
-        any_temp = any(self.slots[i].temperature > 0 for i in active)
-        tr = self.tracer
-        csp = tr.begin("SPECULATE", cat="engine",
-                       track=("serving:engine", "dispatch"),
-                       active=len(active), k=k,
-                       proposed=proposed) if tr is not None else None
         t0 = time.perf_counter()
         chunk_out = None
         args = (self.params, self.cache, jnp.asarray(tokens),
@@ -1944,11 +2043,16 @@ class DecodeEngine:
                 jnp.asarray(chunk_plan.offs))
         else:
             greedy, logits, self.cache = self._verify_fn(*args)
-        # ONE host sync per round; raw logits transfer only under
-        # temperature (greedy needs just the device argmax)
-        greedy = np.asarray(greedy)
-        logits_np = (np.asarray(logits).astype(np.float32)
-                     if any_temp else None)
+        return t0, greedy, logits, chunk_out
+
+    def _commit_spec(self, active: list, drafts: dict, kinds: dict,
+                     greedy: np.ndarray, logits_np, t0: float) -> int:
+        """Accept each lane's run after the verify sync, then bill,
+        finish, requeue and count.  Returns the tokens emitted."""
+        k = self.speculate
+        st = self.spec_stats
+        proposed = sum(len(drafts[i]) for i in active)
+        tr = self.tracer
         self.metrics.histogram("serve_decode_seconds",
                                "batched decode-step latency").observe(
             time.perf_counter() - t0)
@@ -2021,8 +2125,6 @@ class DecodeEngine:
                     self._requeue_starved(i)
                 else:
                     self._finish(i)
-        if csp is not None:
-            tr.end(csp, ts=ts_sync, tokens=total, accepted=accepted_total)
         self.admission.charge_bulk(charges)
         self.metrics.counter("serve_tokens_generated").inc(total)
         tok_counter = self.metrics.counter(
@@ -2043,24 +2145,32 @@ class DecodeEngine:
                 METRIC_SPEC_ACCEPT_RATE,
                 "running draft acceptance rate").set(
                 st["accepted"] / st["proposed"])
-        return total, chunk_out
+        return total
 
     def _step_host(self, active: list):
         """Original per-token host loop (baseline / reference path)."""
-        token = jnp.asarray(self.last_tok[:, None])
-        pos = jnp.asarray(self.pos.astype(np.int32))
-        t0 = time.perf_counter()
-        if self.paging is not None:
-            logits, self.cache = self._step(
-                self.params, self.cache, token, pos,
-                jnp.asarray(self._dispatch_table()))
-        else:
-            logits, self.cache = self._step(self.params, self.cache, token,
-                                            pos)
-        self.metrics.histogram("serve_decode_seconds",
-                               "batched decode-step latency").observe(
-            time.perf_counter() - t0)
-        nxt = self._sample(logits)
+        tr = self.tracer
+        with phase("engine.dispatch", tr):
+            token = jnp.asarray(self.last_tok[:, None])
+            pos = jnp.asarray(self.pos.astype(np.int32))
+            t0 = time.perf_counter()
+            if self.paging is not None:
+                logits, self.cache = self._step(
+                    self.params, self.cache, token, pos,
+                    jnp.asarray(self._dispatch_table()))
+            else:
+                logits, self.cache = self._step(self.params, self.cache,
+                                                token, pos)
+            self.metrics.histogram("serve_decode_seconds",
+                                   "batched decode-step latency").observe(
+                time.perf_counter() - t0)
+        with self._sync():
+            nxt = self._sample(logits)
+        with phase("engine.commit", tr):
+            self._commit_host(active, nxt)
+
+    def _commit_host(self, active: list, nxt: np.ndarray):
+        """Append each lane's sampled token, then bill and finish."""
         tr = self.tracer
         ts_sync = tr.clock() if tr is not None else 0.0
         tenant_tokens: dict[str, int] = {}

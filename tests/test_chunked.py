@@ -162,6 +162,38 @@ def test_preempt_mid_prefill_round_trips_at_chunk_boundary(tiny_model):
     assert ref == {0: list(scav.output), 1: list(hi.output)}
 
 
+def test_preempting_a_partial_beside_a_listed_twin(tiny_model):
+    """Two scavenger partials share one rid, so comparing them by value
+    reaches their prompt arrays (of unequal length).  A high-QOS arrival
+    evicts the later one mid-prefill: it leaves the partial list by
+    identity, its twin keeps prefilling, and every output matches
+    classic whole-prompt serving."""
+    cfg, params = tiny_model
+    scavs = [Request(rid=7, prompt=(np.arange(40 + 8 * i, dtype=np.int32)
+                                    % 50), max_new_tokens=3,
+                     qos="scavenger") for i in range(2)]
+    hi = Request(rid=8, prompt=(np.arange(12, dtype=np.int32) % 50) + 1,
+                 max_new_tokens=3, qos="high")
+    eng = DecodeEngine(cfg, params, num_slots=2, cache_len=128,
+                       kv_page_size=8, max_batch_tokens=8)
+    for r in scavs:
+        eng.submit(r)
+    eng.step()
+    assert [p.req for p in eng._partials] == scavs
+    eng.submit(hi)
+    eng.step()
+    victim = scavs[1]
+    assert victim.preemptions == 1 and scavs[0].preemptions == 0
+    assert [p.req for p in eng._partials] == [scavs[0], hi]
+    eng.run_to_completion()
+    assert all(r.done for r in scavs + [hi])
+    for r in scavs + [hi]:
+        ref = Request(rid=0, prompt=r.prompt.copy(), max_new_tokens=3)
+        _run(DecodeEngine(cfg, params, num_slots=1, cache_len=128,
+                          kv_page_size=8), [ref])
+        assert list(r.output) == list(ref.output)
+
+
 # ---------------------------------------------------- SSM front-pad ----
 
 def test_ssm_front_padded_buckets_identical(tiny_model):

@@ -19,11 +19,14 @@ import json
 import numpy as np
 import pytest
 
-from repro.monitoring import MetricsRegistry, SLOTarget, Tracer
+from repro.monitoring import (
+    DEFAULT_SLO_TARGETS, LATENCY_BUCKETS, MetricsRegistry, SLOTarget, Tracer,
+)
 from repro.monitoring.metrics import _labels_text
 from repro.monitoring.trace import (
-    METRIC_SERVE_ITL, METRIC_SERVE_QUEUE_WAIT, METRIC_SERVE_TTFT,
-    METRIC_SLO_TTFT_MET, METRIC_SLO_TTFT_VIOLATIONS,
+    ENGINE_TRACK, METRIC_SERVE_ITL, METRIC_SERVE_QUEUE_WAIT,
+    METRIC_SERVE_TTFT, METRIC_SLO_ITL_MET, METRIC_SLO_ITL_VIOLATIONS,
+    METRIC_SLO_TTFT_MET, METRIC_SLO_TTFT_VIOLATIONS, phase,
 )
 
 
@@ -234,6 +237,54 @@ def test_slo_itl_is_token_weighted():
     assert hist.sum(tenant="a", qos="normal") == pytest.approx(0.08)
 
 
+@pytest.mark.parametrize("seconds,n,qos", [
+    (0.01, 8, "normal"), (0.3, 8, "high"), (0.9, 1, "normal"),
+    (0.05, 5, "scavenger")])
+def test_weighted_itl_matches_per_token_observations(seconds, n, qos):
+    """One observation of weight n: the same bucket counts and the same
+    attainment counters as n observations of the same latency."""
+    tr = Tracer(clock=FakeClock())
+    tr.slo.itl(seconds, "a", qos, n=n)
+    ref = MetricsRegistry()
+    hist = ref.histogram(METRIC_SERVE_ITL, buckets=LATENCY_BUCKETS)
+    target = DEFAULT_SLO_TARGETS[qos].itl_s
+    for _ in range(n):
+        hist.observe(seconds, tenant="a", qos=qos)
+        if target is not None:
+            ref.counter(METRIC_SLO_ITL_MET if seconds <= target
+                        else METRIC_SLO_ITL_VIOLATIONS).inc(
+                tenant="a", qos=qos)
+
+    def buckets(h):
+        return [ln for ln in h.expose() if "_bucket" in ln]
+
+    got = tr.metrics.histogram(METRIC_SERVE_ITL)
+    assert buckets(got) == buckets(hist)
+    assert got.count(tenant="a", qos=qos) == n
+    assert got.sum(tenant="a", qos=qos) == pytest.approx(
+        hist.sum(tenant="a", qos=qos))
+    for name in (METRIC_SLO_ITL_MET, METRIC_SLO_ITL_VIOLATIONS):
+        assert tr.metrics.counter(name).value(tenant="a", qos=qos) \
+            == ref.counter(name).value(tenant="a", qos=qos)
+
+
+def test_phase_without_a_tracer_is_one_profiler_annotation():
+    from jax.profiler import TraceAnnotation
+    assert type(phase("engine.sync")) is TraceAnnotation
+    with phase("engine.sync", None):
+        pass
+
+
+def test_phase_with_a_tracer_is_also_an_engine_span():
+    clk = FakeClock()
+    tr = Tracer(clock=clk)
+    with phase("engine.plan", tr):
+        clk.advance(0.25)
+    (sp,) = tr.spans(name="engine.plan")
+    assert sp.track == ENGINE_TRACK and sp.cat == "engine"
+    assert (sp.start, sp.end) == (0.0, 0.25)
+
+
 def test_slo_report_lists_tenants():
     tr = Tracer(clock=FakeClock())
     tr.slo.ttft(0.1, "alice", "high")
@@ -302,11 +353,90 @@ def test_preempted_request_traces_two_decode_segments(tiny_model, tmp_path):
     assert itl.count(tenant="research", qos="scavenger") > 0
     # every lifecycle state reached the trace, and the export is valid
     names = {s.name for s in tracer.spans()}
-    assert {"QUEUED", "PREFILL", "DECODE", "decode_chunk"} <= names
+    assert {"QUEUED", "PREFILL", "DECODE", "engine.dispatch",
+            "engine.sync"} <= names
     path = tmp_path / "serve_trace.json"
     data = tracer.export_chrome(str(path))
     ts = [e["ts"] for e in data["traceEvents"] if e["ph"] != "M"]
     assert ts == sorted(ts) and len(ts) > 10
+
+
+def _host_events(logdir) -> list:
+    """(name, start_ns, end_ns) of every ``engine.*`` host event in the
+    profiler trace under ``logdir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True)
+    return sorted((ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                  for plane in ProfileData.from_file(path).planes
+                  if plane.name.startswith("/host:")
+                  for ln in plane.lines for ev in ln.events
+                  if ev.name.startswith("engine."))
+
+
+def test_engine_phases_tile_each_step_under_the_profiler(tiny_model,
+                                                         tmp_path):
+    """Every engine.* phase of a budgeted engine lies inside an
+    engine.step; the outermost phases of a step do not overlap and
+    cover at least 95% of it."""
+    import jax
+
+    from repro.serving import DecodeEngine, Request
+
+    cfg, params = tiny_model
+    rng = np.random.default_rng(11)
+    eng = DecodeEngine(cfg, params, num_slots=2, cache_len=64,
+                       kv_page_size=8, max_batch_tokens=16, decode_chunk=4)
+    for i in range(4):
+        eng.submit(Request(rid=i, prompt=rng.integers(
+            0, cfg.vocab_size, 10 + 9 * i).astype(np.int32),
+            max_new_tokens=6))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    left = 1
+    while left:
+        with jax.profiler.TraceAnnotation("engine.step"):
+            left = eng.step()
+    jax.profiler.stop_trace()
+    events = _host_events(tmp_path)
+    steps = [(s, e) for n, s, e in events if n == "engine.step"]
+    phases = [(s, e, n) for n, s, e in events if n != "engine.step"]
+    assert len(steps) >= 5
+    assert {n for _, _, n in phases} == {
+        "engine.admit", "engine.plan", "engine.dispatch", "engine.sync",
+        "engine.commit"}
+    inside = 0
+    for s0, e0 in steps:
+        mine = sorted((s, e) for s, e, _ in phases if s0 <= s and e <= e0)
+        inside += len(mine)
+        outer = [(s, e) for s, e in mine
+                 if not any(a <= s and e <= b and (a, b) != (s, e)
+                            for a, b in mine)]
+        assert all(a[1] <= b[0] for a, b in zip(outer, outer[1:]))
+        assert sum(e - s for s, e in outer) >= 0.95 * (e0 - s0)
+    assert inside == len(phases)
+
+
+def test_engine_programs_have_unique_names(tiny_model):
+    """A profiler trace names each jitted program after its function
+    (``jit_<name>``): every builder's is its own."""
+    from repro.serving import DecodeEngine
+
+    cfg, params = tiny_model
+    eng = DecodeEngine(cfg, params, num_slots=2, cache_len=64,
+                       kv_page_size=8, max_batch_tokens=16, decode_chunk=4,
+                       prefix_cache=True, speculate=2)
+    names = {attr: getattr(eng, attr).__name__ for attr in (
+        "_step", "_decode_n", "_insert", "_prefill_fn", "_suffix_prefill_fn",
+        "_chunk_fn", "_mixed_step", "_verify_fn", "_mixed_verify")}
+    assert names == {
+        "_step": "step_paged", "_decode_n": "step_n_paged",
+        "_insert": "insert_paged", "_prefill_fn": "prefill_fn",
+        "_suffix_prefill_fn": "suffix_fn", "_chunk_fn": "chunk_fn",
+        "_mixed_step": "mixed", "_verify_fn": "verify",
+        "_mixed_verify": "mixed_verify"}
 
 
 def test_untraced_engine_has_no_trace_state(tiny_model):
@@ -319,7 +449,7 @@ def test_untraced_engine_has_no_trace_state(tiny_model):
                 max_new_tokens=4)
     eng.submit(r)
     eng.run_to_completion()
-    assert r._trace == {} and r._t_admit is None
+    assert r._trace == {} and r._t_last is None
     assert eng.metrics.histogram(METRIC_SERVE_TTFT).count() == 0
 
 
