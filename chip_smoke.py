@@ -209,13 +209,15 @@ def check_kernels(S: int = 2048, Sp: int = 1024, seed: int = 0) -> None:
     table = jnp.asarray(
         1 + rng.permutation(pages - 1)[:B * n_pages].reshape(B, n_pages),
         jnp.int32)
-    kp, vp = bf16(pages, ps, K, Dh), bf16(pages, ps, K, Dh)
+    # layer 1 of a two-layer stacked pool, read in place
+    kp, vp = bf16(2, pages, K, ps, Dh), bf16(2, pages, K, ps, Dh)
+    layer = jnp.int32(1)
     paged = jax.jit(ops.flash_decode_paged)
     with jax.default_matmul_precision("highest"):
-        want = ref.paged_decode_attention_ref(f32(q), f32(kp), f32(vp),
-                                              table, pos)
+        want = ref.paged_decode_attention_ref(f32(q), f32(kp[1]),
+                                              f32(vp[1]), table, pos)
     compare(f"flash_decode paged {n_pages} pages of {ps}",
-            paged(q, kp, vp, table, pos), want)
+            paged(q, kp, vp, table, pos, layer), want)
 
     qa, ka, va = bf16(1, Sp, H, Dh), bf16(1, Sp, K, Dh), bf16(1, Sp, K, Dh)
     attn = jax.jit(ops.flash_attention)

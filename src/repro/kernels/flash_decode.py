@@ -36,7 +36,8 @@ math is otherwise unchanged, so ring decode keeps the flat-latency
 property of the full-cache kernel.
 
 Layout contract (from ops.py): q (B, K, G, Dh) grouped queries;
-k/v (B, K, S, Dh); pos (B,) int32.
+k/v (B, K, S, Dh); pos (B,) int32.  The paged kernel reads the serving
+pool in its stored layout, (layers, num_pages, K, page_size, Dh).
 """
 from __future__ import annotations
 
@@ -174,17 +175,24 @@ def flash_decode_bkgd(q, k, v, pos, *, block_k: int = DEFAULT_BLOCK_K,
 # regardless of which physical page backs them, so masking (and therefore
 # numerics) is identical to the dense split-KV kernel with block_k = ps.
 # Unallocated tails resolve to the null page and are fully masked.
+#
+# The kernel reads the model's stacked pool where it lies: k/v are
+# (layers, num_pages, K, page_size, Dh), heads inside a page, so one
+# (page_size, Dh) block is one contiguous tile, and the layer rides in
+# SMEM beside the table.  Nothing is sliced or transposed per call.
 
 
-def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_ref, l_ref, *, page_size: int, scale: float):
+def _paged_decode_kernel(layer_ref, pt_ref, pos_ref, q_ref, k_ref, v_ref,
+                         o_ref, m_ref, l_ref, *, page_size: int,
+                         scale: float):
+    del layer_ref                                     # used by index_maps
     b = pl.program_id(0)
     pi = pl.program_id(2)
     pos = pos_ref[b]
 
     q = q_ref[0, 0].astype(jnp.float32)               # (G, Dh)
-    k = k_ref[0, 0].astype(jnp.float32)               # (ps, Dh)
-    v = v_ref[0, 0].astype(jnp.float32)               # (ps, Dh)
+    k = k_ref[0, 0, 0].astype(jnp.float32)            # (ps, Dh)
+    v = v_ref[0, 0, 0].astype(jnp.float32)            # (ps, Dh)
     G = q.shape[0]
 
     s = jax.lax.dot_general(
@@ -209,46 +217,49 @@ def _paged_decode_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def flash_decode_paged_bkgd(q, k, v, page_table, pos, *,
+def flash_decode_paged_bkgd(q, k, v, page_table, pos, layer, *,
                             interpret: bool = False):
-    """Split-KV decode against a paged pool.
+    """Split-KV decode against one layer of a stacked paged pool.
 
-    q: (B, K, G, Dh) grouped queries; k/v: (K, num_pages, page_size, Dh)
-    pools; page_table: (B, n_pages) int32 (logical page -> physical page,
-    0 = null page); pos: (B,) int32 — sequence b attends logical KV
-    positions [0, pos_b].  Returns (B, K, G, Dh).
+    q: (B, K, G, Dh) grouped queries; k/v: (layers, num_pages, K,
+    page_size, Dh) pools; page_table: (B, n_pages) int32 (logical page ->
+    physical page, 0 = null page); pos: (B,) int32 — sequence b attends
+    logical KV positions [0, pos_b]; layer: int32 scalar, the pool layer
+    read.  Returns (B, K, G, Dh).
 
     One grid cell per (sequence, kv head, logical page); each fetches its
-    physical page via the prefetched table and emits an online-softmax
-    partial, combined across pages in jnp exactly like the dense kernel.
+    physical page of ``layer`` via the prefetched scalars and emits an
+    online-softmax partial, combined across pages in jnp exactly like
+    the dense kernel.
     """
     B, K, G, Dh = q.shape
-    page_size = k.shape[2]
+    page_size = k.shape[3]
     n_pages = page_table.shape[1]
     scale = Dh ** -0.5
 
     kernel = functools.partial(_paged_decode_kernel, page_size=page_size,
                                scale=scale)
+    kv_spec = pl.BlockSpec(
+        (1, 1, 1, page_size, Dh),
+        lambda b, h, pi, lyr, pt, pos: (lyr[0], pt[b, pi], h, 0, 0))
     o_part, m_part, l_part = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(B, K, n_pages),
             in_specs=[
                 pl.BlockSpec((1, 1, G, Dh),
-                             lambda b, h, pi, pt, pos: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, page_size, Dh),
-                             lambda b, h, pi, pt, pos: (h, pt[b, pi], 0, 0)),
-                pl.BlockSpec((1, 1, page_size, Dh),
-                             lambda b, h, pi, pt, pos: (h, pt[b, pi], 0, 0)),
+                             lambda b, h, pi, lyr, pt, pos: (b, h, 0, 0)),
+                kv_spec,
+                kv_spec,
             ],
             out_specs=[
                 pl.BlockSpec((1, 1, 1, G, Dh),
-                             lambda b, h, pi, pt, pos: (b, h, pi, 0, 0)),
+                             lambda b, h, pi, lyr, pt, pos: (b, h, pi, 0, 0)),
                 pl.BlockSpec((1, 1, 1, G, _LANES),
-                             lambda b, h, pi, pt, pos: (b, h, pi, 0, 0)),
+                             lambda b, h, pi, lyr, pt, pos: (b, h, pi, 0, 0)),
                 pl.BlockSpec((1, 1, 1, G, _LANES),
-                             lambda b, h, pi, pt, pos: (b, h, pi, 0, 0)),
+                             lambda b, h, pi, lyr, pt, pos: (b, h, pi, 0, 0)),
             ],
         ),
         out_shape=[
@@ -257,6 +268,7 @@ def flash_decode_paged_bkgd(q, k, v, page_table, pos, *,
             jax.ShapeDtypeStruct((B, K, n_pages, G, _LANES), jnp.float32),
         ],
         interpret=interpret,
-    )(page_table.astype(jnp.int32), pos.astype(jnp.int32), q, k, v)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      page_table.astype(jnp.int32), pos.astype(jnp.int32), q, k, v)
 
     return _combine(o_part, m_part, l_part, q.dtype)

@@ -1,8 +1,10 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Model code calls these with model-layout tensors; the wrappers transpose to
-kernel layout, pick hardware-aligned block sizes, and run the kernel —
-``interpret=True`` on CPU (this container), compiled on real TPU.
+Model code calls these with model-layout tensors; the wrappers transpose
+activations and dense caches to kernel layout, pick hardware-aligned block
+sizes, and run the kernel — ``interpret=True`` on the CPU backend, compiled
+on a TPU.  The paged pool is stored in the layout its kernel reads, so
+:func:`flash_decode_paged` hands it over untouched.
 """
 from __future__ import annotations
 
@@ -72,13 +74,14 @@ def flash_decode(q, k, v, pos, block_k: int = 512, interpret=None,
     return o.reshape(B, H, Dh)[:, None]
 
 
-def flash_decode_paged(q, k, v, page_table, pos, interpret=None):
+def flash_decode_paged(q, k, v, page_table, pos, layer, interpret=None):
     """Model-layout wrapper for paged single-query decode attention.
 
-    q: (B, 1, H, Dh) roped query; k/v: (num_pages, page_size, K, Dh)
-    shared page pool (H % K == 0); page_table: (B, n_pages) int32 mapping
-    logical to physical pages (0 = null page); pos: (B,) int32 — attends
-    logical positions [0, pos_b].  Returns (B, 1, H, Dh).
+    q: (B, 1, H, Dh) roped query; k/v: (layers, num_pages, K, page_size,
+    Dh) the model's stacked page pool, read in place (H % K == 0);
+    page_table: (B, n_pages) int32 mapping logical to physical pages (0 =
+    null page); pos: (B,) int32 — attends logical positions [0, pos_b];
+    layer: int32 scalar, the pool layer attended.  Returns (B, 1, H, Dh).
     """
     if interpret is None:
         interpret = _on_cpu()
@@ -86,9 +89,7 @@ def flash_decode_paged(q, k, v, page_table, pos, interpret=None):
     K = k.shape[2]
     G = H // K
     qg = q[:, 0].reshape(B, K, G, Dh)                # grouped like the model
-    kt = k.transpose(2, 0, 1, 3)                     # (K, num_pages, ps, Dh)
-    vt = v.transpose(2, 0, 1, 3)
-    o = flash_decode_paged_bkgd(qg, kt, vt, page_table, pos,
+    o = flash_decode_paged_bkgd(qg, k, v, page_table, pos, layer,
                                 interpret=interpret)
     return o.reshape(B, H, Dh)[:, None]
 

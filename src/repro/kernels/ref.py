@@ -57,18 +57,20 @@ def decode_attention_ref(q, k, v, pos):
 def paged_decode_attention_ref(q, k, v, page_table, pos):
     """Naive paged decode attention (the paged flash-decode oracle).
 
-    q: (B,1,H,Dh); k/v: (num_pages, page_size, K, Dh) shared pool;
-    page_table: (B, n_pages) int32 (0 = null page); pos: (B,) int32.
-    Gathers the logical (B, n_pages*page_size, K, Dh) view through the
-    page table, then defers to :func:`decode_attention_ref`.
+    q: (B,1,H,Dh); k/v: (num_pages, K, page_size, Dh) one layer of the
+    shared pool; page_table: (B, n_pages) int32 (0 = null page); pos:
+    (B,) int32.  Gathers the logical (B, n_pages*page_size, K, Dh) view
+    through the page table, then defers to :func:`decode_attention_ref`.
     """
     B = q.shape[0]
     n_pages = page_table.shape[1]
-    ps = k.shape[1]
-    K, Dh = k.shape[2], k.shape[3]
-    kd = k[page_table].reshape(B, n_pages * ps, K, Dh)
-    vd = v[page_table].reshape(B, n_pages * ps, K, Dh)
-    return decode_attention_ref(q, kd, vd, pos)
+    K, ps, Dh = k.shape[1], k.shape[2], k.shape[3]
+
+    def logical(pool):
+        return pool[page_table].transpose(0, 1, 3, 2, 4).reshape(
+            B, n_pages * ps, K, Dh)
+
+    return decode_attention_ref(q, logical(k), logical(v), pos)
 
 
 def ssd_ref(x, dt, A, Bm, Cm):

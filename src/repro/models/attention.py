@@ -199,15 +199,17 @@ def init_kv_cache(cfg: ModelConfig, batch: int, cache_len: int, n_groups: int,
 
     ``paging`` (a :class:`repro.models.paging.PagedKVConfig`) switches the
     layout from dense per-slot lines ``(G, B, slots, K, Dh)`` to one shared
-    page pool ``(G, num_pages, page_size, K, Dh)``: requests address it
+    page pool ``(G, num_pages, K, page_size, Dh)``: requests address it
     through a per-slot page table instead of a batch index, so the pool is
-    sized to the HBM budget rather than ``batch * cache_len``.  Callers
-    that indexed the cache by batch must go through the page table in this
-    mode (see README "Paged KV cache" migration note).
+    sized to the HBM budget rather than ``batch * cache_len``.  Heads sit
+    inside a page, so one (page_size, Dh) block is the tile the paged
+    decode kernel reads straight from the pool.  Callers that indexed the
+    cache by batch must go through the page table in this mode (see
+    README "Paged KV cache" migration note).
     """
     if paging is not None:
-        shape = (n_groups, paging.num_pages, paging.page_size,
-                 cfg.num_kv_heads, cfg.head_dim)
+        shape = (n_groups, paging.num_pages, cfg.num_kv_heads,
+                 paging.page_size, cfg.head_dim)
     else:
         slots = min(cache_len, cfg.sliding_window or cache_len)
         shape = (n_groups, batch, slots, cfg.num_kv_heads, cfg.head_dim)
@@ -278,15 +280,52 @@ def attention_prefill(p: dict, x: jax.Array, cfg: ModelConfig,
     return out_proj(p, o), {"k": ck, "v": cv}
 
 
+def paged_view(pool: jax.Array, layer, page_table: jax.Array):
+    """One layer of a stacked pool ``(G, num_pages, K, ps, Dh)`` seen
+    through ``page_table`` ((B, n) int32): the logical (B, n * ps, K, Dh)
+    lines, line i of row b at position i — the dense cache's layout.
+    One gather, so only the table's pages are read."""
+    B, n = page_table.shape
+    _, _, K, ps, Dh = pool.shape
+    return pool[layer, page_table].transpose(0, 1, 3, 2, 4).reshape(
+        B, n * ps, K, Dh)
+
+
+def write_lines(pool: jax.Array, layer, pages, offs, lines):
+    """Write KV ``lines`` (``pages.shape + (K, Dh)``) into a stacked pool
+    ``(G, num_pages, K, ps, Dh)`` at per-line physical ``(layer, pages,
+    offs)``; ``layer`` is a scalar or broadcasts against ``pages``.  A
+    scatter of just these lines: on a carried or donated pool XLA
+    updates it in place.  Each (line, head) is its own (Dh,) row, so the
+    scatter's window is the pool's minor dim alone and leaves the pool in
+    the row-major layout the paged kernel reads (a (K, Dh) window would
+    have the compiler re-lay the pool with heads minor, and copy it back
+    around every kernel call)."""
+    idx = (jnp.asarray(layer)[..., None], pages[..., None],
+           jnp.arange(pool.shape[2]), offs[..., None])
+    return pool.at[idx].set(lines.astype(pool.dtype))
+
+
+def write_pages(pool: jax.Array, page_ids, lines):
+    """Write whole pages into every layer of a stacked pool ``(G,
+    num_pages, K, ps, Dh)``: ``lines`` (G, n * ps, K, Dh) fill pages
+    ``page_ids`` ((n,) int32) in order."""
+    G, _, K, ps, Dh = pool.shape
+    pages = lines.reshape(G, page_ids.shape[0], ps, K, Dh)
+    return pool.at[:, page_ids].set(
+        pages.transpose(0, 1, 3, 2, 4).astype(pool.dtype))
+
+
 def attention_prefill_paged(p: dict, x: jax.Array, cache: dict,
-                            page_table: jax.Array, start: jax.Array,
+                            layer, page_table: jax.Array, start: jax.Array,
                             cfg: ModelConfig):
     """Suffix prefill against a cached prefix in the paged KV pool
     (prefix-cache reuse: only the un-cached tail of the prompt runs).
 
     x: (B, S, d) hidden states of the *suffix* tokens, at absolute
-    positions ``start + [0, S)``; cache k/v: (num_pages, page_size, K,
-    Dh) — the shared pool; page_table: (B, n_prefix_pages) int32 rows
+    positions ``start + [0, S)``; cache k/v: (G, num_pages, K, page_size,
+    Dh) — the shared stacked pool, of which ``layer`` (int32 scalar) is
+    read; page_table: (B, n_prefix_pages) int32 rows
     whose first ``ceil(start / page_size)`` entries are the request's
     READ-ONLY shared prefix pages (any remaining entries null — callers
     may bucket the row width to the match depth so cost scales with the
@@ -316,11 +355,10 @@ def attention_prefill_paged(p: dict, x: jax.Array, cache: dict,
     H, Dh = q.shape[2], q.shape[3]
     K = k.shape[2]
     G = H // K
-    ps = cache["k"].shape[1]
-    L = page_table.shape[1] * ps                     # logical prefix width
+    kd = paged_view(cache["k"], layer, page_table).astype(q.dtype)
+    vd = paged_view(cache["v"], layer, page_table).astype(q.dtype)
+    L = kd.shape[1]                                  # logical prefix width
     scale = Dh ** -0.5
-    kd = cache["k"][page_table].reshape(B, L, K, Dh).astype(q.dtype)
-    vd = cache["v"][page_table].reshape(B, L, K, Dh).astype(q.dtype)
     qg = q.reshape(B, S, K, G, Dh)
     # prefix part: every suffix query sees every position < start (all
     # causal by construction); pool garbage past start is masked out
@@ -346,15 +384,16 @@ def attention_prefill_paged(p: dict, x: jax.Array, cache: dict,
     return out_proj(p, constrain(o, "heads")), {"k": k, "v": v}
 
 
-def attention_verify(p: dict, x: jax.Array, cache: dict, pos0: jax.Array,
-                     pages: jax.Array, offs: jax.Array,
+def attention_verify(p: dict, x: jax.Array, cache: dict, layer,
+                     pos0: jax.Array, pages: jax.Array, offs: jax.Array,
                      page_table: jax.Array, cfg: ModelConfig):
     """Multi-position decode against a paged KV pool (speculative verify).
 
     x: (B, S, d) hidden states of S consecutive tokens per slot — the
     slot's last sampled token followed by S-1 drafted continuations, at
-    absolute positions ``pos0 + [0, S)``; cache k/v: (num_pages,
-    page_size, K, Dh) — the shared pool; pages/offs: (B, S) int32
+    absolute positions ``pos0 + [0, S)``; cache k/v: (G, num_pages, K,
+    page_size, Dh) — the shared stacked pool, of which ``layer`` (int32
+    scalar) is written and read; pages/offs: (B, S) int32
     physical scatter targets for each token's KV line (the null page for
     lines past a slot's allocation or for dead slots); page_table:
     (B, pages_per_seq) as in :func:`attention_decode_paged`.
@@ -369,11 +408,10 @@ def attention_verify(p: dict, x: jax.Array, cache: dict, pos0: jax.Array,
     truncates ``pos`` host-side, and the next round's scatter overwrites
     them before any query can attend past its own position.
 
-    Returns (out (B,S,d), updated cache).
+    Returns (out (B,S,d), updated stacked cache).
     """
     assert cfg.sliding_window is None, "paged KV is full-attention only"
     B, S, _ = x.shape
-    ps = cache["k"].shape[1]
     q, k, v = qkv_proj(p, x, cfg)                     # (B,S,H/K,Dh)
     posm = pos0[:, None].astype(jnp.int32) + jnp.arange(S)[None, :]
     if cfg.pos_embedding == "rope":
@@ -381,17 +419,16 @@ def attention_verify(p: dict, x: jax.Array, cache: dict, pos0: jax.Array,
         k = apply_rope(k, posm, cfg.rope_theta)
     # scatter all S lines; duplicate writes only ever target the null
     # page (dead slots / over-capacity lines), same as bucket pad lines
-    ck = cache["k"].at[pages, offs].set(k.astype(cache["k"].dtype))
-    cv = cache["v"].at[pages, offs].set(v.astype(cache["v"].dtype))
+    ck = write_lines(cache["k"], layer, pages, offs, k)
+    cv = write_lines(cache["v"], layer, pages, offs, v)
 
     H, Dh = q.shape[2], q.shape[3]
     K = ck.shape[2]
     G = H // K
-    n_pages = page_table.shape[1]
-    L = n_pages * ps
     qg = q.reshape(B, S, K, G, Dh)
-    kd = ck[page_table].reshape(B, L, K, Dh)
-    vd = cv[page_table].reshape(B, L, K, Dh)
+    kd = paged_view(ck, layer, page_table)
+    vd = paged_view(cv, layer, page_table)
+    L = kd.shape[1]
     valid = jnp.arange(L)[None, None, :] <= posm[:, :, None]   # (B,S,L)
     logits = jnp.einsum("bqkgd,bskd->bkgqs", qg, kd).astype(jnp.float32)
     logits = logits * (Dh ** -0.5)
@@ -451,32 +488,37 @@ def attention_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array,
     return out_proj(p, constrain(o, "heads")), {"k": ck, "v": cv}
 
 
-def attention_decode_paged(p: dict, x: jax.Array, cache: dict,
+def attention_decode_paged(p: dict, x: jax.Array, cache: dict, layer,
                            pos: jax.Array, page_table: jax.Array,
                            cfg: ModelConfig, use_pallas: bool = False):
     """One-token decode against a paged KV pool.
 
-    x: (B,1,d); cache k/v: (num_pages, page_size, K, Dh) — the shared
-    pool, NOT per-batch; page_table: (B, pages_per_seq) int32 mapping each
-    sequence's logical page j to a physical pool page (entry 0 = the null
-    page for unallocated tails); pos: scalar or (B,) int32 absolute
-    position of the new token.
+    x: (B,1,d); cache k/v: (G, num_pages, K, page_size, Dh) — the shared
+    pool stacked over layer groups, NOT per-batch; layer: int32 scalar,
+    the group written and read; page_table: (B, pages_per_seq) int32
+    mapping each sequence's logical page j to a physical pool page
+    (entry 0 = the null page for unallocated tails); pos: scalar or (B,)
+    int32 absolute position of the new token.
 
-    The new K/V line lands at physical ``(page_table[b, pos//ps],
-    pos % ps)``; attention gathers the pool through the page table into a
-    (B, pages_per_seq*ps, K, Dh) logical view and then runs exactly the
-    dense full-cache math — masked logits are the same array the dense
-    path produces, so greedy decode is bit-identical to the dense cache
-    (pool garbage beyond ``pos`` is masked to the same ``_NEG_INF``).
+    The new K/V line lands at physical ``(layer, page_table[b, pos//ps],
+    :, pos % ps)``, a scatter of B lines into the stacked pool; nothing
+    else of the pool is copied.  ``use_pallas`` hands the whole stacked
+    pool and ``layer`` to the paged kernel, which reads the table's pages
+    where they lie.  Otherwise attention gathers the layer through the
+    page table into a (B, pages_per_seq*ps, K, Dh) logical view and runs
+    exactly the dense full-cache math — masked logits are the same array
+    the dense path produces, so greedy decode is bit-identical to the
+    dense cache (pool garbage beyond ``pos`` is masked to the same
+    ``_NEG_INF``).
 
     Full-attention only: the paged pool has no ring layout, so callers
     gate ``sliding_window`` configs to the dense path.
 
-    Returns (out (B,1,d), updated cache).
+    Returns (out (B,1,d), updated stacked cache).
     """
     assert cfg.sliding_window is None, "paged KV is full-attention only"
     B = x.shape[0]
-    ps = cache["k"].shape[1]
+    ps = cache["k"].shape[3]
     q, k, v = qkv_proj(p, x, cfg)                     # (B,1,H/K,Dh)
     posv = jnp.broadcast_to(pos, (B,)).astype(jnp.int32)
     if cfg.pos_embedding == "rope":
@@ -487,23 +529,22 @@ def attention_decode_paged(p: dict, x: jax.Array, cache: dict,
     off = posv % ps
     # live slots own disjoint pages; dead/frozen slots all target the null
     # page, whose contents are never read unmasked
-    ck = cache["k"].at[page, off].set(k[:, 0].astype(cache["k"].dtype))
-    cv = cache["v"].at[page, off].set(v[:, 0].astype(cache["v"].dtype))
+    ck = write_lines(cache["k"], layer, page, off, k[:, 0])
+    cv = write_lines(cache["v"], layer, page, off, v[:, 0])
 
     if use_pallas:
         from repro.kernels import ops as kops
-        o = kops.flash_decode_paged(q, ck, cv, page_table, posv)
+        o = kops.flash_decode_paged(q, ck, cv, page_table, posv, layer)
         return out_proj(p, constrain(o, "heads")), {"k": ck, "v": cv}
 
     H, Dh = q.shape[2], q.shape[3]
     K = ck.shape[2]
     G = H // K
-    n_pages = page_table.shape[1]
     qg = q.reshape(B, 1, K, G, Dh)
     # gather the logical view: (B, n_pages*ps, K, Dh)
-    kd = ck[page_table].reshape(B, n_pages * ps, K, Dh)
-    vd = cv[page_table].reshape(B, n_pages * ps, K, Dh)
-    valid = jnp.arange(n_pages * ps)[None, :] <= posv[:, None]
+    kd = paged_view(ck, layer, page_table)
+    vd = paged_view(cv, layer, page_table)
+    valid = jnp.arange(kd.shape[1])[None, :] <= posv[:, None]
     logits = jnp.einsum("bqkgd,bskd->bkgqs", qg, kd).astype(jnp.float32)
     logits = logits * (Dh ** -0.5)
     logits = jnp.where(valid[:, None, None, None, :], logits, _NEG_INF)

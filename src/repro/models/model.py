@@ -105,6 +105,65 @@ def sublayer_train(p, x, mixer: str, ffn: str, cfg: ModelConfig,
     return x, aux
 
 
+def _ffn_block(p, x, ffn: str, cfg: ModelConfig):
+    """The sublayer's FFN half (norm, MLP or MoE, residual) at inference."""
+    if ffn == "none":
+        return x
+    hh = rmsnorm(x, p["norm2"]["scale"], cfg.norm_eps)
+    if ffn == "moe":
+        hh, _ = MOE.moe_apply(p["moe"], hh, cfg)
+    else:
+        hh = mlp_apply(p["mlp"], hh, cfg.mlp_type)
+    return constrain(x + hh, "hidden")
+
+
+def _paged_layers(params, cache, x, attend, cfg: ModelConfig,
+                  run: RunConfig, remat: bool = False):
+    """Run the layer groups against the stacked paged pools.
+
+    ``attend(p_attn, h, pool, g) -> (h, pool, out)`` is one attention
+    sublayer: ``pool`` is that sublayer's ``{"k", "v"}`` pool, each leaf
+    ``(G, num_pages, K, page_size, Dh)``, and ``g`` the int32 group index
+    it reads and writes at.  The pools ride whole in the loop's carry,
+    never in ``xs``/``ys``: no layer's pool is sliced out and stacked
+    back, so a layer's new lines are written in place and its pages are
+    read where they lie.  Returns (x, {"layers": [...]} pools, ``out``s
+    stacked over groups, a tuple per sublayer)."""
+    sched = layer_schedule(cfg)[:group_period(cfg)]
+    assert all(mixer == "attn" for mixer, _ in sched), \
+        "the paged KV pool is full-attention only"
+
+    def step(carry, inp):
+        x, pools = carry
+        group_params, g = inp
+        pools, outs = list(pools), []
+        for i, (_mixer, ffn) in enumerate(sched):
+            p = group_params[i]
+            hh = rmsnorm(x, p["norm1"]["scale"], cfg.norm_eps)
+            hh, pools[i], out = attend(p["attn"], hh, pools[i], g)
+            x = _ffn_block(p, constrain(x + hh, "hidden"), ffn, cfg)
+            outs.append(out)
+        return (x, tuple(pools)), tuple(outs)
+
+    if remat:
+        step = jax.checkpoint(step, prevent_cse=False)
+    carry = (x, tuple(cache["layers"]))
+    n_groups = jax.tree.leaves(params["layers"])[0].shape[0]
+    if run.unroll:
+        per_group = []
+        for g in range(n_groups):
+            gp = jax.tree.map(lambda l: l[g], tuple(params["layers"]))
+            carry, out = step(carry, (gp, g))
+            per_group.append(out)
+        outs = jax.tree.map(lambda *xs: jnp.stack(xs), *per_group)
+    else:
+        carry, outs = jax.lax.scan(
+            step, carry,
+            (tuple(params["layers"]), jnp.arange(n_groups, dtype=jnp.int32)))
+    x, pools = carry
+    return x, {"layers": list(pools)}, outs
+
+
 # --------------------------------------------------------------- forward ----
 
 def backbone_train(params, h, cfg: ModelConfig, run: RunConfig):
@@ -172,7 +231,8 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int,
     """Decode cache pytree: one entry per sublayer slot, stacked over groups.
 
     ``paging`` (a :class:`repro.models.paging.PagedKVConfig`) makes every
-    attention sublayer a shared page pool instead of ``batch`` dense rows;
+    attention sublayer a shared page pool ``(G, num_pages, K, page_size,
+    Dh)`` instead of ``batch`` dense rows ``(G, batch, slots, K, Dh)``;
     decode must then pass the matching ``page_table``.  Paged mode is
     full-attention only (SSM state is not line-addressable), so callers
     gate on ``cfg.ssm is None``.
@@ -199,8 +259,9 @@ def cache_logical_axes(cfg: ModelConfig, paging: bool = False):
     layers = []
     for mixer, _ in sched:
         if mixer == "attn":
-            # paged pools have no batch dim: pages replace (batch, seq)
-            ax = (("layers", None, "cache_seq", "kv_heads", "head_dim")
+            # paged pools have no batch dim: pages replace (batch, seq),
+            # and heads sit outside a page's lines
+            ax = (("layers", None, "kv_heads", "cache_seq", "head_dim")
                   if paging else
                   ("layers", "batch", "cache_seq", "kv_heads", "head_dim"))
             layers.append({"k": ax, "v": ax})
@@ -213,16 +274,17 @@ def cache_logical_axes(cfg: ModelConfig, paging: bool = False):
 
 
 def cache_kv_head_dim(cfg: ModelConfig, paging: bool = False) -> int:
-    """Index of the ``kv_heads`` axis in every attention KV-cache leaf.
+    """Index of the ``kv_heads`` axis in every attention KV-cache leaf of
+    one layout.
 
-    All engine-level cache layouts — the paged pool ``(G, pages, ps, K,
-    Dh)``, dense rows ``(G, B, slots, K, Dh)``, and one-request
-    prefill/chunk slices ``(G, B, S, K, Dh)`` — carry ``kv_heads`` at
-    the same position, which is what lets serving TP cover the whole
-    cache pytree with a single PartitionSpec prefix
-    (``serving.tp.cache_pspec``).  Derived from
-    :func:`cache_logical_axes` so a future layout change breaks loudly
-    here instead of silently mis-sharding."""
+    ``paging=True``: the paged pool ``(G, pages, K, ps, Dh)``, dim 2.
+    ``paging=False``: dense rows ``(G, B, slots, K, Dh)`` and the
+    one-request prefill/chunk slices ``(G, B, S, K, Dh)`` that are
+    scattered into either cache, dim 3.  Within one layout every leaf
+    agrees, so serving TP covers a cache pytree with a single
+    PartitionSpec prefix per layout (``serving.tp.cache_pspec``).
+    Derived from :func:`cache_logical_axes` so a future layout change
+    breaks loudly here instead of silently mis-sharding."""
     for leaf in cache_logical_axes(cfg, paging=paging)["layers"]:
         for axes in leaf.values():
             if "kv_heads" in axes:
@@ -279,7 +341,7 @@ def prefill(params, batch: dict, cfg: ModelConfig, run: RunConfig,
 
     def group_body(x, group_params):
         new_caches = []
-        for i, (mixer, _ffn) in enumerate(sched):
+        for i, (mixer, ffn) in enumerate(sched):
             p = group_params[i]
             hh = rmsnorm(x, p["norm1"]["scale"], cfg.norm_eps)
             if mixer == "attn":
@@ -294,15 +356,7 @@ def prefill(params, batch: dict, cfg: ModelConfig, run: RunConfig,
                     valid=valid,
                     conv_end=(None if front_pad is None
                               else front_pad + num_real))
-            x = constrain(x + hh, "hidden")
-            ffn = sched[i][1]
-            if ffn != "none":
-                hh = rmsnorm(x, p["norm2"]["scale"], cfg.norm_eps)
-                if ffn == "moe":
-                    hh, _ = MOE.moe_apply(p["moe"], hh, cfg)
-                else:
-                    hh = mlp_apply(p["mlp"], hh, cfg.mlp_type)
-                x = constrain(x + hh, "hidden")
+            x = _ffn_block(p, constrain(x + hh, "hidden"), ffn, cfg)
             new_caches.append(c)
         return x, tuple(new_caches)
 
@@ -345,13 +399,11 @@ def prefill_suffix(params, batch: dict, cache, page_table, start,
 
     Returns (logits (B,1,V), {"layers": [...]} suffix cache slices, each
     (G, B, S, K, Dh)) — the caller scatters the slices into its
-    privately-owned pages; the shared pages are never written.
+    privately-owned pages; the shared pages are never written.  Each
+    layer reads its pages through its group index in the stacked pool
+    (:func:`_paged_layers`), so no layer's pool is sliced out.
     Full-attention configs only (paging already gates SSM/ring out).
     """
-    P = group_period(cfg)
-    sched = layer_schedule(cfg)[:P]
-    assert all(mixer == "attn" for mixer, _ in sched), \
-        "prefix reuse is full-attention only"
     h = embed_tokens(params, batch["tokens"], cfg)
     S = h.shape[1]
     if cfg.pos_embedding == "sinusoidal":
@@ -359,39 +411,13 @@ def prefill_suffix(params, batch: dict, cache, page_table, start,
         h = h + pe[None].astype(h.dtype)
     h = constrain(h, "hidden")
 
-    def group_body(x, inp):
-        group_params, group_cache = inp
-        new_caches = []
-        for i, (_mixer, ffn) in enumerate(sched):
-            p = group_params[i]
-            hh = rmsnorm(x, p["norm1"]["scale"], cfg.norm_eps)
-            hh, c = A.attention_prefill_paged(p["attn"], hh, group_cache[i],
-                                              page_table, start, cfg)
-            x = constrain(x + hh, "hidden")
-            if ffn != "none":
-                hh = rmsnorm(x, p["norm2"]["scale"], cfg.norm_eps)
-                if ffn == "moe":
-                    hh, _ = MOE.moe_apply(p["moe"], hh, cfg)
-                else:
-                    hh = mlp_apply(p["mlp"], hh, cfg.mlp_type)
-                x = constrain(x + hh, "hidden")
-            new_caches.append(c)
-        return x, tuple(new_caches)
+    def attend(p, hh, pool, g):
+        hh, c = A.attention_prefill_paged(p, hh, pool, g, page_table,
+                                          start, cfg)
+        return hh, pool, c
 
-    if run.remat in ("layer", "full"):
-        group_body = jax.checkpoint(group_body, prevent_cse=False)
-    if run.unroll:
-        n_groups = jax.tree.leaves(params["layers"])[0].shape[0]
-        per_group = []
-        for g in range(n_groups):
-            gp = jax.tree.map(lambda l: l[g], tuple(params["layers"]))
-            gc = jax.tree.map(lambda l: l[g], tuple(cache["layers"]))
-            h, c = group_body(h, (gp, gc))
-            per_group.append(c)
-        caches = jax.tree.map(lambda *xs: jnp.stack(xs), *per_group)
-    else:
-        h, caches = jax.lax.scan(
-            group_body, h, (tuple(params["layers"]), tuple(cache["layers"])))
+    h, _, caches = _paged_layers(params, cache, h, attend, cfg, run,
+                                 remat=run.remat in ("layer", "full"))
     h = rmsnorm(h, params["final_norm"]["scale"], cfg.norm_eps)
     if last_pos is None:
         h_last = h[:, -1:]
@@ -445,8 +471,10 @@ def decode_step(params, cache, token, pos, cfg: ModelConfig, run: RunConfig,
     """One decoding step.  token: (B, 1) int32; pos: scalar int32 OR (B,)
     int32 (0-based absolute position of each new token — vector form for
     continuous batching).  ``page_table`` ((B, n_pages) int32) routes
-    attention through the paged KV pool instead of dense per-slot rows.
-    Returns (logits (B,1,V), new cache)."""
+    attention through the paged KV pool instead of dense per-slot rows:
+    the stacked pool is carried through the layer loop, each layer writes
+    its B new lines in place and reads its pages where they lie
+    (:func:`_paged_layers`).  Returns (logits (B,1,V), new cache)."""
     P = group_period(cfg)
     sched = layer_schedule(cfg)[:P]
     B = token.shape[0]
@@ -456,6 +484,17 @@ def decode_step(params, cache, token, pos, cfg: ModelConfig, run: RunConfig,
         pe = A.sinusoidal_pe(posv[:, None], cfg.d_model)   # (B,1,d)
         h = h + pe.astype(h.dtype)
 
+    if page_table is not None:
+        def attend(p, hh, pool, g):
+            hh, pool = A.attention_decode_paged(
+                p, hh, pool, g, pos, page_table, cfg,
+                use_pallas=run.use_pallas)
+            return hh, pool, None
+
+        h, new_cache, _ = _paged_layers(params, cache, h, attend, cfg, run)
+        h = rmsnorm(h, params["final_norm"]["scale"], cfg.norm_eps)
+        return unembed(params, h, cfg), new_cache
+
     def group_body(x, inp):
         group_params, group_cache = inp
         new_caches = []
@@ -463,24 +502,12 @@ def decode_step(params, cache, token, pos, cfg: ModelConfig, run: RunConfig,
             p = group_params[i]
             hh = rmsnorm(x, p["norm1"]["scale"], cfg.norm_eps)
             if mixer == "attn":
-                if page_table is not None:
-                    hh, c = A.attention_decode_paged(
-                        p["attn"], hh, group_cache[i], pos, page_table,
-                        cfg, use_pallas=run.use_pallas)
-                else:
-                    hh, c = A.attention_decode(p["attn"], hh, group_cache[i],
-                                               pos, cfg,
-                                               use_pallas=run.use_pallas)
+                hh, c = A.attention_decode(p["attn"], hh, group_cache[i],
+                                           pos, cfg,
+                                           use_pallas=run.use_pallas)
             else:
                 hh, c = SSM.ssm_decode(p["ssm"], hh, group_cache[i], cfg)
-            x = constrain(x + hh, "hidden")
-            if ffn != "none":
-                hh = rmsnorm(x, p["norm2"]["scale"], cfg.norm_eps)
-                if ffn == "moe":
-                    hh, _ = MOE.moe_apply(p["moe"], hh, cfg)
-                else:
-                    hh = mlp_apply(p["mlp"], hh, cfg.mlp_type)
-                x = constrain(x + hh, "hidden")
+            x = _ffn_block(p, constrain(x + hh, "hidden"), ffn, cfg)
             new_caches.append(c)
         return x, tuple(new_caches)
 
@@ -522,13 +549,10 @@ def verify_tokens(params, cache, tokens, pos0, pages, offs, page_table,
     :func:`repro.models.attention.attention_verify`), so greedy
     speculation is bit-identical to sequential decode.
 
-    Returns (logits (B, S, V), new cache).  Full-attention configs only
-    (paging already gates SSM/ring out).
+    Returns (logits (B, S, V), new cache).  The stacked pool rides in
+    the layer loop's carry like :func:`decode_step`'s.  Full-attention
+    configs only (paging already gates SSM/ring out).
     """
-    P = group_period(cfg)
-    sched = layer_schedule(cfg)[:P]
-    assert all(mixer == "attn" for mixer, _ in sched), \
-        "speculative verify is full-attention only"
     h = embed_tokens(params, tokens, cfg)
     if cfg.pos_embedding == "sinusoidal":
         S = tokens.shape[1]
@@ -537,40 +561,14 @@ def verify_tokens(params, cache, tokens, pos0, pages, offs, page_table,
         h = h + pe.astype(h.dtype)
     h = constrain(h, "hidden")
 
-    def group_body(x, inp):
-        group_params, group_cache = inp
-        new_caches = []
-        for i, (_mixer, ffn) in enumerate(sched):
-            p = group_params[i]
-            hh = rmsnorm(x, p["norm1"]["scale"], cfg.norm_eps)
-            hh, c = A.attention_verify(p["attn"], hh, group_cache[i],
-                                       pos0, pages, offs, page_table, cfg)
-            x = constrain(x + hh, "hidden")
-            if ffn != "none":
-                hh = rmsnorm(x, p["norm2"]["scale"], cfg.norm_eps)
-                if ffn == "moe":
-                    hh, _ = MOE.moe_apply(p["moe"], hh, cfg)
-                else:
-                    hh = mlp_apply(p["mlp"], hh, cfg.mlp_type)
-                x = constrain(x + hh, "hidden")
-            new_caches.append(c)
-        return x, tuple(new_caches)
+    def attend(p, hh, pool, g):
+        hh, pool = A.attention_verify(p, hh, pool, g, pos0, pages, offs,
+                                      page_table, cfg)
+        return hh, pool, None
 
-    if run.unroll:
-        n_groups = jax.tree.leaves(params["layers"])[0].shape[0]
-        per_group = []
-        for g in range(n_groups):
-            gp = jax.tree.map(lambda l: l[g], tuple(params["layers"]))
-            gc = jax.tree.map(lambda l: l[g], tuple(cache["layers"]))
-            h, c = group_body(h, (gp, gc))
-            per_group.append(c)
-        new_layers = jax.tree.map(lambda *xs: jnp.stack(xs), *per_group)
-    else:
-        h, new_layers = jax.lax.scan(
-            group_body, h, (tuple(params["layers"]), tuple(cache["layers"])))
+    h, new_cache, _ = _paged_layers(params, cache, h, attend, cfg, run)
     h = rmsnorm(h, params["final_norm"]["scale"], cfg.norm_eps)
-    logits = unembed(params, h, cfg)
-    return logits, {"layers": list(new_layers)}
+    return unembed(params, h, cfg), new_cache
 
 
 # ------------------------------------------------- fused decode fast path ----

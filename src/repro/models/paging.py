@@ -5,7 +5,7 @@ whole lifetime — a short request strands HBM exactly the way an idle node
 strands a SLURM partition.  Paging (vLLM's PagedAttention) breaks the cache
 into fixed-size *pages* drawn from one device-resident pool:
 
-* **pool** — ``(n_groups, num_pages, page_size, K, Dh)`` per attention
+* **pool** — ``(n_groups, num_pages, K, page_size, Dh)`` per attention
   sublayer, allocated once (``models.attention.init_kv_cache(paging=...)``);
 * **page table** — per-slot ``(pages_per_seq,)`` int32 mapping logical page
   ``j`` (KV lines ``[j*page_size, (j+1)*page_size)``) to a physical page in

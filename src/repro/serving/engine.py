@@ -98,6 +98,7 @@ from jax.sharding import PartitionSpec as PSpec
 
 from repro.configs.base import ModelConfig, RunConfig
 from repro.models import init_cache, prefill
+from repro.models.attention import write_lines, write_pages
 from repro.models.model import (
     decode_n, decode_step, prefill_chunk, prefill_suffix, verify_tokens,
 )
@@ -221,8 +222,6 @@ class DecodeEngine:
             print(f"[serve] tp: {note}")
         self._pp = tp_mod.param_pspecs(cfg, self.tp) if self.tp.active \
             else None
-        self._cc = tp_mod.cache_pspec(self.tp, cfg) if self.tp.active \
-            else None
         self.num_slots = num_slots
         self.cache_len = cache_len
         self.metrics = metrics or MetricsRegistry()
@@ -237,6 +236,14 @@ class DecodeEngine:
         self.decode_chunk = max(1, int(decode_chunk))
         self.fused = fused
         self.paging = self._resolve_paging(kv_page_size, kv_pages)
+        # the engine's cache (the paged pool, or dense rows) and the
+        # one-request slices scattered into it carry kv heads at their
+        # own dims, so each gets its own spec
+        self._cc = tp_mod.cache_pspec(
+            self.tp, cfg, paging=self.paging is not None) \
+            if self.tp.active else None
+        self._cs = tp_mod.cache_pspec(self.tp, cfg) if self.tp.active \
+            else None
         if self.paging is not None:
             # one page bills like the lines it holds, keeping fair-share
             # comparable across page sizes and with dense engines on the
@@ -293,8 +300,9 @@ class DecodeEngine:
             self.params = jax.device_put(
                 self.params, tp_mod.param_shardings(cfg, self.tp))
             self.cache = jax.device_put(
-                self.cache, tp_mod.cache_shardings(self.cache, self.tp,
-                                                   cfg))
+                self.cache, tp_mod.cache_shardings(
+                    self.cache, self.tp, cfg,
+                    paging=self.paging is not None))
         elif device is not None:
             # one replica per device: committed params and pool pull every
             # jitted program of this engine onto ``device``
@@ -469,12 +477,13 @@ class DecodeEngine:
     def _tp_wrap(self, fn, in_kinds: str, out_kinds: str,
                  donate: tuple = ()):
         """``tp.wrap`` with per-argument specs named by kind: ``p`` the
-        params pytree, ``c`` a cache pytree (prefix spec — every 5-D
-        leaf carries kv_heads at dim 3), ``r`` replicated.  Inactive
-        plans compile to a plain ``jax.jit`` with identical semantics."""
+        params pytree, ``c`` the engine's cache (the paged pool, kv heads
+        at dim 2, or dense rows, at dim 3), ``s`` one-request cache
+        slices (kv heads at dim 3), ``r`` replicated.  Inactive plans
+        compile to a plain ``jax.jit`` with identical semantics."""
         if not self.tp.active:
             return tp_mod.wrap(self.tp, fn, (), (), donate)
-        m = {"p": self._pp, "c": self._cc, "r": PSpec()}
+        m = {"p": self._pp, "c": self._cc, "s": self._cs, "r": PSpec()}
         ins = tuple(m[k] for k in in_kinds)
         outs = (m[out_kinds] if len(out_kinds) == 1
                 else tuple(m[k] for k in out_kinds))
@@ -530,19 +539,17 @@ class DecodeEngine:
                 # pad-tail pages ride on the null page (id 0), whose
                 # garbage is never read unmasked
                 def put(pool_leaf, one_leaf):
-                    g, _, length = one_leaf.shape[:3]
+                    length = one_leaf.shape[2]
                     n = page_ids.shape[0]
                     lines = one_leaf[:, 0]
                     if n * ps > length:
                         lines = jnp.pad(
                             lines, ((0, 0), (0, n * ps - length),
                                     (0, 0), (0, 0)))
-                    pages = lines.reshape(g, n, ps, *lines.shape[2:])
-                    return pool_leaf.at[:, page_ids].set(
-                        pages.astype(pool_leaf.dtype))
+                    return write_pages(pool_leaf, page_ids, lines)
                 return jax.tree.map(put, pool_cache, one_cache)
 
-            return self._tp_wrap(insert_paged, "ccr", "c", donate=(0,))
+            return self._tp_wrap(insert_paged, "csr", "c", donate=(0,))
 
         def insert(batch_cache, one_cache, slot):
             def put(batch_leaf, one_leaf):
@@ -551,7 +558,7 @@ class DecodeEngine:
                     axis=1)
             return jax.tree.map(put, batch_cache, one_cache)
 
-        return self._tp_wrap(insert, "ccr", "c", donate=(0,))
+        return self._tp_wrap(insert, "csr", "c", donate=(0,))
 
     def _build_prefill(self):
         cfg, run, cache_len = self.cfg, self.run, self.cache_len
@@ -569,14 +576,14 @@ class DecodeEngine:
                                cache_len=cache_len, last_pos=last_pos,
                                front_pad=front_pad, num_real=num_real)
 
-            return self._tp_wrap(prefill_front_fn, "prrrr", "rc")
+            return self._tp_wrap(prefill_front_fn, "prrrr", "rs")
 
         def prefill_fn(params, tokens, last_pos):
             return prefill(params, {"tokens": tokens}, cfg, run,
                            cache_len=None if paged else cache_len,
                            last_pos=last_pos)
 
-        return self._tp_wrap(prefill_fn, "prr", "rc")
+        return self._tp_wrap(prefill_fn, "prr", "rs")
 
     def _build_suffix_prefill(self):
         """Jitted suffix prefill for prefix-cache hits: compiles once per
@@ -589,7 +596,7 @@ class DecodeEngine:
                                   page_table, start, cfg, run,
                                   last_pos=last_pos)
 
-        return self._tp_wrap(suffix_fn, "pcrrrr", "rc")
+        return self._tp_wrap(suffix_fn, "pcrrrr", "rs")
 
     @staticmethod
     def _scatter_chunk(cache, slices, pages, offs):
@@ -597,10 +604,12 @@ class DecodeEngine:
         (page, offset) targets.  Unlike the whole-page admission insert,
         a chunk may start and end mid-page, so the write is
         line-granular; pad lines target the null page (harmless
-        duplicate writes).  Traced inside the chunk/mixed programs."""
+        duplicate writes).  Traced inside the chunk/mixed programs, on
+        the donated pool: a scatter of just these lines, in place."""
         def put(pool_leaf, one_leaf):
-            lines = one_leaf[:, 0].astype(pool_leaf.dtype)   # (G,C,K,Dh)
-            return pool_leaf.at[:, pages, offs].set(lines)
+            layers = jnp.arange(pool_leaf.shape[0])[:, None]
+            return write_lines(pool_leaf, layers, pages[None], offs[None],
+                               one_leaf[:, 0])             # (G,C,K,Dh)
         return jax.tree.map(put, cache, slices)
 
     def _build_chunk_prefill(self):

@@ -31,7 +31,7 @@ two differences that keep decode fast and bit-identical:
   not sharded by the serving path yet and fall back the same way.
 
 The paged KV pool shards with attention: each device holds the
-``(G, num_pages, page_size, K/tp, Dh)`` slice of every page, page *ids*
+``(G, num_pages, K/tp, page_size, Dh)`` slice of every page, page *ids*
 stay a single host-side space (one logical page = one id = ``tp``
 device-local slices), so the allocator, prefix-cache refcounts and COW
 forks remain shard-agnostic host logic.  Admission sees the pool
@@ -175,20 +175,21 @@ def param_pspecs(cfg: ModelConfig, plan: TPPlan):
     return build(model_spec(cfg))
 
 
-def cache_pspec(plan: TPPlan, cfg: Optional[ModelConfig] = None) -> P:
-    """PartitionSpec for ONE KV-cache leaf.
+def cache_pspec(plan: TPPlan, cfg: Optional[ModelConfig] = None,
+                paging: bool = False) -> P:
+    """PartitionSpec for ONE KV-cache leaf of one layout.
 
-    Every engine-level cache layout — paged pool ``(G, pages, ps, K,
-    Dh)``, dense rows ``(G, B, slots, K, Dh)``, one-request prefill
-    output and chunk slices ``(G, B, S, K, Dh)`` — carries ``kv_heads``
-    at the same dim of a 5-D leaf
-    (:func:`repro.models.model.cache_kv_head_dim`), so a single spec
-    covers all of them.  Used as a pytree *prefix* over the whole
-    ``{"layers": [{"k","v"}]}`` cache (SSM leaves never co-exist with
-    ``shard_attn``)."""
+    ``paging=True``: the paged pool ``(G, pages, K, ps, Dh)``, kv heads
+    at dim 2.  ``paging=False``: dense rows ``(G, B, slots, K, Dh)`` and
+    the one-request prefill output and chunk slices ``(G, B, S, K, Dh)``
+    that are scattered into either cache, kv heads at dim 3
+    (:func:`repro.models.model.cache_kv_head_dim`).  Used as a pytree
+    *prefix* over a whole ``{"layers": [{"k","v"}]}`` cache of that
+    layout (SSM leaves never co-exist with ``shard_attn``)."""
     if not plan.shard_attn:
         return P()
-    kv_dim = 3 if cfg is None else cache_kv_head_dim(cfg)
+    kv_dim = ((2 if paging else 3) if cfg is None
+              else cache_kv_head_dim(cfg, paging=paging))
     spec = [None] * 5
     spec[kv_dim] = plan.axis
     return P(*spec)
@@ -213,9 +214,10 @@ def param_shardings(cfg: ModelConfig, plan: TPPlan):
 
 
 def cache_shardings(cache, plan: TPPlan,
-                    cfg: Optional[ModelConfig] = None):
-    """NamedSharding pytree for placing the engine cache on the mesh."""
-    spec = cache_pspec(plan, cfg)
+                    cfg: Optional[ModelConfig] = None, paging: bool = False):
+    """NamedSharding pytree for placing the engine cache (the paged pool
+    when ``paging``, else dense rows) on the mesh."""
+    spec = cache_pspec(plan, cfg, paging=paging)
 
     def leaf(x):
         return NamedSharding(plan.mesh, spec if x.ndim == 5 else P())

@@ -93,17 +93,56 @@ PAGED_CASES = [
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_flash_decode_matches_oracle(B, H, K, Dh, ps, pool, npages,
                                            dtype):
+    """The kernel reads layer ``l`` of a stacked (layers, pages, K, ps,
+    Dh) pool in place; every layer matches the oracle on that layer."""
+    layers = 3
     q = jnp.asarray(RNG.standard_normal((B, 1, H, Dh)), dtype)
-    k = jnp.asarray(RNG.standard_normal((pool, ps, K, Dh)), dtype)
-    v = jnp.asarray(RNG.standard_normal((pool, ps, K, Dh)), dtype)
+    k = jnp.asarray(RNG.standard_normal((layers, pool, K, ps, Dh)), dtype)
+    v = jnp.asarray(RNG.standard_normal((layers, pool, K, ps, Dh)), dtype)
     table = jnp.asarray(RNG.integers(1, pool, (B, npages)), jnp.int32)
     pos = jnp.asarray(RNG.integers(0, npages * ps, B), jnp.int32)
-    out = ops.flash_decode_paged(q, k, v, table, pos, interpret=True)
-    ref = paged_decode_attention_ref(q, k, v, table, pos)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(ref, np.float32),
-        atol=tol, rtol=tol)
+    for layer in range(layers):
+        out = ops.flash_decode_paged(q, k, v, table, pos,
+                                     jnp.int32(layer), interpret=True)
+        ref = paged_decode_attention_ref(q, k[layer], v[layer], table, pos)
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32), np.asarray(ref, np.float32),
+            atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("pos0", [6, 7, 8, 9, 23])
+def test_paged_flash_decode_tails_and_page_boundaries(pos0):
+    """Two lanes on disjoint pages of a stacked pool, each table row
+    ending in null pages; ``pos`` on the last line of a page (7), on the
+    first line of the next (8) and either side of them.  Every layer
+    matches the oracle, and poisoning everything the lanes may not read
+    (other layers, the null page, lines past ``pos``) changes nothing."""
+    layers, pool, K, ps, Dh, H = 3, 9, 2, 8, 32, 4
+    q = jnp.asarray(RNG.standard_normal((2, 1, H, Dh)), jnp.float32)
+    k = RNG.standard_normal((layers, pool, K, ps, Dh)).astype(np.float32)
+    v = RNG.standard_normal((layers, pool, K, ps, Dh)).astype(np.float32)
+    # lane 0 owns pages 3, 5, 1; lane 1 owns 2, 8, 4; tails are null
+    table = np.array([[3, 5, 1, 0, 0], [2, 8, 4, 0, 0]], np.int32)
+    pos = np.array([pos0, min(pos0 + 1, 3 * ps - 1)], np.int32)
+    for layer in range(layers):
+        want = paged_decode_attention_ref(q, k[layer], v[layer], table, pos)
+        got = ops.flash_decode_paged(q, jnp.asarray(k), jnp.asarray(v),
+                                     table, pos, jnp.int32(layer),
+                                     interpret=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)
+        poisoned_k, poisoned_v = np.full_like(k, 1e4), np.full_like(v, 1e4)
+        for b in range(2):
+            for line in range(pos[b] + 1):
+                page, off = table[b, line // ps], line % ps
+                poisoned_k[layer, page, :, off] = k[layer, page, :, off]
+                poisoned_v[layer, page, :, off] = v[layer, page, :, off]
+        again = ops.flash_decode_paged(
+            q, jnp.asarray(poisoned_k), jnp.asarray(poisoned_v), table, pos,
+            jnp.int32(layer), interpret=True)
+        np.testing.assert_allclose(np.asarray(again), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)
 
 
 # ------------------------------------------------------ engine identity ----
@@ -123,6 +162,57 @@ def test_paged_greedy_bit_identical_to_dense(tiny_model):
         assert [r.output for r in got] == [r.output for r in ref], page_size
         assert eng.allocator.in_use == 0
         assert (eng.page_tables == NULL_PAGE).all()
+
+
+def _paged_program_args(eng, name, cache, C=8, S=3):
+    """Arguments of one of the engine's paged programs, with ``cache`` in
+    the pool's place (C: chunk tokens, S: verified tokens a lane)."""
+    n, pps = eng.num_slots, eng.paging.pages_per_seq
+    i32 = lambda *shape: np.zeros(shape, np.int32)  # noqa: E731
+    table = i32(n, pps)
+    lanes = [i32(n), i32(n), i32(n), np.ones(n, bool),
+             np.full(n, -1, np.int32), np.zeros(n, np.float32), eng._key,
+             table, np.full(n, eng.cache_len, np.int32)]
+    chunk = [i32(1, C), i32(1, pps), np.int32(0), np.int32(C - 1),
+             i32(C), i32(C)]
+    return {"decode_n": [eng.params, cache] + lanes,
+            "mixed_step": [eng.params, cache] + lanes + chunk,
+            "chunk_fn": [eng.params, cache] + chunk,
+            "verify_fn": [eng.params, cache, i32(n, S), i32(n), i32(n, S),
+                          i32(n, S), table]}[name]
+
+
+@pytest.mark.parametrize("program",
+                         ["decode_n", "mixed_step", "chunk_fn", "verify_fn"])
+def test_paged_programs_copy_no_layer_pool(program):
+    """Guard against whole-pool copies in the paged programs: the layer
+    loop carries the stacked pool, writes only the new lines and reads
+    pages where they lie, so the compiled program's scratch stays below
+    ONE layer's K+V pool although the pool is several times everything
+    else.  Slicing a layer's pool out of the stack, stacking it back, or
+    re-laying it for the kernel each need at least that much.
+
+    float32 model and pool: the CPU backend widens a bfloat16 scatter to
+    float32 over the whole operand (the TPU scatters bfloat16 in place,
+    ``tests/test_tpu_compile.py`` holds that), which would hide the
+    layout under test here."""
+    import jax
+    from repro.models import init_params
+    cfg = dataclasses.replace(get_reduced_config("stablelm-3b"),
+                              dtype="float32", param_dtype="float32")
+    eng = DecodeEngine(cfg, init_params(cfg, 0), num_slots=4, cache_len=64,
+                       kv_page_size=16, kv_pages=1025, decode_chunk=4,
+                       max_batch_tokens=16, speculate=2)
+    cache = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, jnp.float32), eng.cache)
+    pool = cache["layers"][0]["k"]
+    layer_kv = 2 * pool.dtype.itemsize * int(np.prod(pool.shape[1:]))
+    params = sum(x.nbytes for x in jax.tree.leaves(eng.params))
+    assert pool.shape[0] * layer_kv > 4 * params
+    fn = getattr(eng, {"decode_n": "_decode_n"}.get(program, "_" + program))
+    compiled = fn.lower(*_paged_program_args(eng, program, cache)).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < layer_kv, (program, temp, layer_kv)
 
 
 def test_paged_host_loop_matches_dense(tiny_model):

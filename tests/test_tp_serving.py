@@ -193,6 +193,9 @@ def test_cache_pspec_targets_kv_head_dim():
     plan = TPPlan(mesh=None, tp=2, shard_attn=True)
     spec = cache_pspec(plan, cfg)
     assert tuple(spec) == (None, None, None, "model", None)
+    # the paged pool (G, pages, K, ps, Dh) carries kv heads at dim 2
+    assert tuple(cache_pspec(plan, cfg, paging=True)) == \
+        (None, None, "model", None, None)
     assert cache_pspec(TPPlan(mesh=None, tp=2, shard_attn=False), cfg) \
         == cache_pspec(TPPlan(mesh=None), None)
 

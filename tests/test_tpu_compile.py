@@ -86,16 +86,18 @@ def test_ring_decode(one_chip):
 @pytest.mark.parametrize("widths", [STABLELM, QWEN2],
                          ids=["mha_dh80", "gqa_dh128"])
 def test_paged_decode(one_chip, widths):
-    """A 1024-line page table over a 257-page pool of 16-line pages."""
+    """A 1024-line page table over a 257-page pool of 16-line pages, one
+    layer of a 4-layer stacked pool read in place."""
     H, K, Dh = widths.values()
-    pages, ps, per_seq = 257, 16, 64
-    _compile(lambda q, k, v, pt, p: ops.flash_decode_paged(
-                 q, k, v, pt, p, interpret=False),
+    layers, pages, ps, per_seq = 4, 257, 16, 64
+    _compile(lambda q, k, v, pt, p, l: ops.flash_decode_paged(
+                 q, k, v, pt, p, l, interpret=False),
              _sds(one_chip, (B, 1, H, Dh)),
-             _sds(one_chip, (pages, ps, K, Dh)),
-             _sds(one_chip, (pages, ps, K, Dh)),
+             _sds(one_chip, (layers, pages, K, ps, Dh)),
+             _sds(one_chip, (layers, pages, K, ps, Dh)),
              _sds(one_chip, (B, per_seq), jnp.int32),
-             _sds(one_chip, (B,), jnp.int32))
+             _sds(one_chip, (B,), jnp.int32),
+             _sds(one_chip, (), jnp.int32))
 
 
 def test_flash_attention(one_chip):
@@ -117,3 +119,43 @@ def test_ssd_scan_mamba2_780m(one_chip):
              _sds(one_chip, (1, S, H), jnp.float32),
              _sds(one_chip, (H,), jnp.float32),
              _sds(one_chip, (1, S, N)), _sds(one_chip, (1, S, N)))
+
+
+def test_paged_decode_program_copies_no_pool(one_chip, monkeypatch):
+    """The engine's fused serve step (a prefill chunk beside a decode
+    chunk), bfloat16 pool and compiled paged kernel: its scratch stays
+    below one layer's K+V pool.  The compiler must neither slice a
+    layer's pool out of the stack nor re-lay the pool for the kernel
+    (a scatter whose window spans heads and lines makes it do so).
+    starcoder2's heads of 128 and 128-line pages, so a page is the
+    chip's (8, 128) tiling exactly, in a pool too large for VMEM."""
+    import dataclasses
+
+    import numpy as np
+    from repro.configs import RunConfig, get_reduced_config
+    from repro.models import init_params
+    from repro.serving import DecodeEngine
+    monkeypatch.setattr(ops, "_on_cpu", lambda: False)
+    cfg = dataclasses.replace(get_reduced_config("starcoder2-3b"),
+                              head_dim=128)
+    eng = DecodeEngine(cfg, init_params(cfg, 0), num_slots=8, cache_len=256,
+                       kv_page_size=128, kv_pages=513, decode_chunk=4,
+                       max_batch_tokens=64,
+                       run=RunConfig(remat="none", use_pallas=True))
+    pool = eng.cache["layers"][0]["k"]
+    layer_kv = 2 * pool.nbytes // pool.shape[0]
+
+    def put(x):
+        return _sds(one_chip, np.shape(x), x.dtype)
+
+    n, pps, C = eng.num_slots, eng.paging.pages_per_seq, 16
+    i32 = lambda *shape: np.zeros(shape, np.int32)  # noqa: E731
+    args = jax.tree.map(put, (
+        eng.params, eng.cache, i32(n), i32(n), i32(n), np.ones(n, bool),
+        i32(n), np.zeros(n, np.float32), np.asarray(eng._key), i32(n, pps),
+        i32(n), i32(1, C), i32(1, pps), np.int32(0), np.int32(0), i32(C),
+        i32(C)))
+    compiled = eng._mixed_step.lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < layer_kv, (temp, layer_kv)
