@@ -2,7 +2,8 @@
 
 After the window, a sample of the window's finished requests, drawn from
 the seed and always holding the one with the most served tokens, is run
-through the plain float32 reference (``bench.reference.dense``) with its
+through the plain float32 reference of the configuration's family (its
+module's ``reference``, such as ``bench/reference/dense.py``) with its
 prompt and served tokens.  The number compared is the widest gap by which
 a served (greedy) token's logit lies below the reference's best at its
 position.  The limit is the configuration's ``check.max_logit_gap``; how
@@ -35,11 +36,28 @@ def sample(requests: list, seed: int, target_tokens: int) -> list:
     return out
 
 
-def max_logit_gap(weights: dict, m, pairs: list, control: bool = False):
+def served_gaps(reference, w: dict, m, prompt: np.ndarray, served,
+                control: bool = False) -> np.ndarray:
+    """For each served token, how far its float32 logit lies below the
+    reference's best at that position, given the prompt and the tokens
+    served before it.  With ``control`` the token is instead the one the
+    reference's float8 control puts first at the same position."""
+    served = np.asarray(served, np.int32)
+    seq = np.concatenate([np.asarray(prompt, np.int32), served])
+    rows = np.arange(len(prompt) - 1, len(seq) - 1)
+    ref = reference.logits(w, m, seq[:-1], rows)
+    pick = served
+    if control:
+        pick = reference.logits(w, m, seq[:-1], rows, fp8=True).argmax(-1)
+    return ref.max(-1) - ref[np.arange(len(rows)), pick]
+
+
+def max_logit_gap(reference, weights: dict, m, pairs: list,
+                  control: bool = False):
     """Widest gap over every served token of ``pairs`` (or, with
     ``control``, over the float8 control's first choices), and the number
     of tokens compared."""
-    from bench.reference.dense import served_gaps
-    gaps = [served_gaps(weights, m, p, s, control=control) for p, s in pairs]
+    gaps = [served_gaps(reference, weights, m, p, s, control=control)
+            for p, s in pairs]
     n = sum(len(g) for g in gaps)
     return (float(max(g.max() for g in gaps)) if n else float("nan")), n

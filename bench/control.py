@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Readings that set a cell's correctness limit: the program's widest
-logit gap on many seeds, and the float8 control's on the same prompts and
-served tokens.
+logit gap on many seeds, and the float8 control's (the family reference's
+``fp8`` path) on the same prompts and served tokens.
 
     python3 bench/control.py --workload <cell> --seeds 1,2,3 --seconds 10
 
@@ -9,8 +9,9 @@ One process and one engine for all seeds (set-up is long): each seed gets
 its own weights and traffic, a short window at the cell's own load, a
 drain that finishes the window's requests, and then both readings over
 the same sample the benchmark's check takes, each also judged by the
-benchmark's own ``run.passed`` against the committed limit.  One
-JSON line per seed; the benchmark's own runs never run this.
+benchmark's own ``run.passed`` against the committed limit, and the
+number of distinct tokens the sample served.  One JSON line per seed;
+the benchmark's own runs never run this.
 """
 from __future__ import annotations
 
@@ -19,6 +20,8 @@ import gc
 import json
 import sys
 from pathlib import Path
+
+import numpy as np
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
@@ -48,19 +51,19 @@ class _WindowOnly:
 def readings(cell, seeds, seconds: float, devices, peak=None) -> list:
     from bench import harness
     from bench.check import max_logit_gap, sample
-    from bench.weights import make_weights, to_program
 
-    m = cell.dims
+    fam, m = cell.family, cell.dims
     limit = cell.config["check"]["max_logit_gap"]
     engine = None
     out = []
     for k, seed in enumerate(seeds):
-        w = make_weights(m, seed, devices[0])
+        w = harness.weights(cell, seed, devices[0])
         if engine is None:
-            engine = harness.build_engine(cell, to_program(w, m))
+            engine = harness.build_engine(cell,
+                                          harness.program_params(cell, w))
             harness.warm_up(engine, cell, seed)
         else:
-            engine.params = to_program(w, m)
+            engine.params = harness.program_params(cell, w)
         schedule = _WindowOnly(harness.make_schedule(cell, seed, seconds),
                                seconds)
         rec = harness.Record(cell=cell, seed=seed, seconds=seconds,
@@ -70,10 +73,12 @@ def readings(cell, seeds, seconds: float, devices, peak=None) -> list:
             pass                    # seed brings other weights
         pairs = sample(rec.requests, seed,
                        cell.config["check"]["sample_tokens"])
-        program, n = max_logit_gap(w, m, pairs)
-        control, _ = max_logit_gap(w, m, pairs, control=True)
+        program, n = max_logit_gap(fam.reference, w, m, pairs)
+        control, _ = max_logit_gap(fam.reference, w, m, pairs, control=True)
         r = {"seed": seed, "program": program, "control": control,
              "tokens": n, "limit": limit,
+             "distinct": len(set(np.concatenate([s for _, s in pairs])
+                                 .tolist())) if pairs else 0,
              "unanswered": sum(t.done_at is None for t in rec.requests)}
         for side in ("program", "control"):
             r[f"{side}_passes"] = run.passed({

@@ -2,7 +2,9 @@
 
 The harness is driven by data.  ``BENCHMARK.json`` names the cell; the
 cell names a configuration file and a traffic mix
-(``bench/traffic/<mix>.json``), the mix names its generator
+(``bench/traffic/<mix>.json``), the configuration names its model family
+(``bench/families/<family>.py``: sizes, weights, reference, counts and
+the check of the program's configuration), the mix names its generator
 (``bench/generators/<name>.py``), and every metric is a reader of its
 own (``bench/metrics/<name>.py``).  Adding any of them adds files and
 entries; nothing here changes.
@@ -16,6 +18,7 @@ from __future__ import annotations
 
 import importlib.util
 import json
+import sys
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
@@ -23,20 +26,28 @@ from typing import Optional
 
 import numpy as np
 
-from bench.model_dims import Dims, dims
-
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
 
 
 # ------------------------------------------------------------- loading ----
 
+_MODULES: dict = {}
+
+
 def load_module(path: Path):
-    spec = importlib.util.spec_from_file_location(
-        "bench_" + path.stem.replace("-", "_").replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    """The module in ``path``, run once per process: a family's ``Dims``
+    stays one class, and what its reference caches stays cached."""
+    path = Path(path).resolve()
+    if path not in _MODULES:
+        name = "_".join(("bench", path.parent.name, path.stem))
+        spec = importlib.util.spec_from_file_location(
+            name.replace("-", "_").replace(".", "_"), path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[spec.name] = mod    # dataclasses look their module up
+        spec.loader.exec_module(mod)
+        _MODULES[path] = mod
+    return _MODULES[path]
 
 
 def _applies(entry: dict, cell: str) -> bool:
@@ -51,10 +62,11 @@ class Cell:
     traffic: dict                 # the traffic file
     end_to_end: list              # BENCHMARK.json metric entries
     per_layer: list
+    family: object = None         # the bench/families/<family>.py module
 
     @property
-    def dims(self) -> Dims:
-        return dims(self.config)
+    def dims(self):
+        return self.family.dims(self.config)
 
 
 def load_cell(name: str, root: Path = ROOT) -> Cell:
@@ -72,14 +84,28 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
 def cell_of(name: str, config_file: Path, traffic: str, chips: int,
             bench: dict, root: Path = ROOT) -> Cell:
     """A cell from its configuration file and traffic mix, with the
-    metrics of ``bench`` (a parsed ``BENCHMARK.json``) that apply to it."""
+    metrics of ``bench`` (a parsed ``BENCHMARK.json``) that apply to it
+    and the family module its configuration names, both under ``root``."""
+    config = json.loads(Path(config_file).read_text())
     return Cell(
-        name=name, chips=chips,
-        config=json.loads(Path(config_file).read_text()),
+        name=name, chips=chips, config=config,
         traffic=json.loads(
             (root / "bench" / "traffic" / f"{traffic}.json").read_text()),
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, name)],
-        per_layer=[m for m in bench["per_layer"] if _applies(m, name)])
+        per_layer=[m for m in bench["per_layer"] if _applies(m, name)],
+        family=load_module(
+            root / "bench" / "families" / f"{config['family']}.py"))
+
+
+def weights(cell: Cell, seed: int, device=None) -> dict:
+    """The seed's weights in the layout of the cell's family, as the
+    reference takes them; ``program_params`` hands them to the program."""
+    from bench.weights import make_weights
+    return make_weights(cell.family.layout(cell.dims), seed, device)
+
+
+def program_params(cell: Cell, w: dict):
+    return cell.family.to_program(w, cell.dims)
 
 
 def make_schedule(cell: Cell, seed: int, seconds: float):
@@ -90,25 +116,17 @@ def make_schedule(cell: Cell, seed: int, seconds: float):
 # ---------------------------------------------------------- the engine ----
 
 def program_config(cell: Cell):
-    """The program's own configuration for the cell's architecture, checked
-    against the sizes the configuration file states."""
+    """The program's own configuration for the cell's architecture in the
+    file's types, checked by the cell's family against the sizes the
+    configuration file states."""
     import dataclasses
 
     from repro.configs import get_config
-    c, m = cell.config, cell.dims
+    c = cell.config
     pc = dataclasses.replace(get_config(c["arch"]),
                              param_dtype=c["dtypes"]["weights"],
-                             dtype=c["dtypes"]["compute"],
-                             rope_theta=m.rope_theta)
-    got = (pc.d_model, pc.d_ff, pc.num_layers, pc.num_heads,
-           pc.num_kv_heads, pc.head_dim, pc.vocab_size, pc.qkv_bias,
-           float(pc.rope_theta), pc.sliding_window)
-    want = (m.d, m.f, m.L, m.H, m.K, m.Dh, m.V, m.qkv_bias, m.rope_theta,
-            c.get("sliding_window"))
-    if got != want:
-        raise SystemExit(f"{c['arch']}: the program's configuration {got} "
-                         f"is not the file's {want}")
-    return pc
+                             dtype=c["dtypes"]["compute"])
+    return cell.family.check_program(c, cell.dims, pc)
 
 
 def build_engine(cell: Cell, params, engine_overrides: Optional[dict] = None):
@@ -209,14 +227,16 @@ class Record:
     window_s: float = 0.0            # until the last step begun in time ends
     end_s: float = 0.0               # when the run stopped waiting
     window_tokens: int = 0           # generated by steps begun in the window
-    decode_tokens: int = 0           # of those, from decode (not prefill)
-    decode_ctx: int = 0              # sum of their context lengths
-    prompt_tokens: int = 0           # prompts whose first token came in it
-    prompt_ctx: int = 0              # sum of their tokens' context lengths
+    # runs (first_ctx, n), which the family's counts read: n tokens of
+    # one request, the first attending over first_ctx lines, each next
+    # over one more.  Decoded: of the window's tokens, those not from
+    # prefill; prompts: (1, P) for each whose first token came in it
+    decode_runs: list = field(default_factory=list)
+    prompt_runs: list = field(default_factory=list)
     requests: list = field(default_factory=list)     # window-due Tracked
     counters: dict = field(default_factory=dict)     # engine, over window
     budget: int = 0                  # engine max_batch_tokens
-    traced_steps: list = field(default_factory=list)  # (decoded, ctx sum)
+    traced_steps: list = field(default_factory=list)  # decode runs a step
     trace: Optional[dict] = None     # bench.trace_reduce.reduce output
     peak: Optional[dict] = None      # bench/peaks.json entry
     window_compiles: int = 0
@@ -321,7 +341,8 @@ def drive(engine, schedule, rec: Record, clock=time.perf_counter,
             engine.step()
         end = clock() - t0
         with _annotation("bench.bookkeeping"):
-            decoded = ctx = gen = 0
+            gen = 0
+            runs = []
             still = []
             for tr in inflight:
                 n = len(tr.req.output)
@@ -330,12 +351,10 @@ def drive(engine, schedule, rec: Record, clock=time.perf_counter,
                     if tr.n_out == 0:
                         tr.first = end
                         if window_open and tr.in_window:
-                            rec.prompt_tokens += P
-                            rec.prompt_ctx += P * (P + 1) // 2
+                            rec.prompt_runs.append((1, P))
                     lo = max(tr.n_out, 1)
                     if n > lo:          # token i decodes over P + i lines
-                        decoded += n - lo
-                        ctx += (n - lo) * P + (lo + n - 1) * (n - lo) // 2
+                        runs.append((P + lo, n - lo))
                     gen += n - tr.n_out
                     tr.n_out, tr.last = n, end
                 if tr.req.done:
@@ -346,10 +365,9 @@ def drive(engine, schedule, rec: Record, clock=time.perf_counter,
             inflight = still
             if window_open:
                 rec.window_tokens += gen
-                rec.decode_tokens += decoded
-                rec.decode_ctx += ctx
+                rec.decode_runs += runs
             if tracing is not None and tracing.on:
-                rec.traced_steps.append((decoded, ctx))
+                rec.traced_steps.append(runs)
                 tracing.maybe_stop(end, engine)
     if tracing is not None:
         tracing.maybe_stop(clock() - t0, engine, force=True)

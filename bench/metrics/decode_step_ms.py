@@ -9,5 +9,5 @@ def read(rec):
     steps = rec.trace["step_busy_s"]
     if len(steps) != len(rec.traced_steps):
         return None
-    busy = [b for b, (decoded, _) in zip(steps, rec.traced_steps) if decoded]
+    busy = [b for b, runs in zip(steps, rec.traced_steps) if runs]
     return 1e3 * sum(busy) / len(busy) if busy else None

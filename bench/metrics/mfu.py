@@ -1,13 +1,13 @@
 """Model FLOPs of every token generated and every prompt token prefilled
-in the window, each at its own context, over the window times the chip's
-peak.  Prompt tokens served from the prefix cache count as work."""
-from bench.flops import model_flops
+in the window, each at its own context (the cell's family's
+``model_flops``), over the window times the chip's peak.  Prompt tokens
+served from the prefix cache count as work."""
 
 
 def read(rec):
     if rec.peak is None or rec.window_s <= 0:
         return None
-    m = rec.cell.dims
-    flops = (model_flops(m, rec.decode_tokens, rec.decode_ctx)
-             + model_flops(m, rec.prompt_tokens, rec.prompt_ctx))
+    fam, m = rec.cell.family, rec.cell.dims
+    flops = (fam.model_flops(m, rec.decode_runs)
+             + fam.model_flops(m, rec.prompt_runs))
     return 100.0 * flops / (rec.window_s * rec.peak["bf16_flops_per_s"])

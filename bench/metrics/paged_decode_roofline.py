@@ -1,10 +1,10 @@
 """The paged decode kernel's share of its roofline in the traced stretch:
 the least time the chip needs for the work the traced steps required
-(each decoded token's live KV lines, ``bench.flops.paged_decode_work``),
-over the kernel's device time in the trace."""
+(each decoded token's live KV lines, the cell's family's
+``paged_decode_work``), over the kernel's device time in the trace."""
 import re
 
-from bench.flops import paged_decode_work, roofline_seconds
+from bench.flops import roofline_seconds
 
 #: the kernel's device operations in the trace: its pallas_call has no
 #: name, so the trace shows the jitted wrapper's
@@ -16,10 +16,8 @@ def read(rec):
     if rec.trace is None or rec.peak is None:
         return None
     t = sum(s for name, s in rec.trace["ops_s"].items() if KERNEL.search(name))
-    tokens = sum(d for d, _ in rec.traced_steps)
-    ctx = sum(c for _, c in rec.traced_steps)
-    if t <= 0 or not tokens:
+    runs = [r for step in rec.traced_steps for r in step]
+    if t <= 0 or not runs:
         return None
-    need = roofline_seconds(*paged_decode_work(rec.cell.dims, tokens, ctx),
-                            rec.peak)
-    return 100.0 * need / t
+    work = rec.cell.family.paged_decode_work(rec.cell.dims, runs)
+    return 100.0 * roofline_seconds(*work, rec.peak) / t

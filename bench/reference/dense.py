@@ -1,5 +1,6 @@
-"""Plain float32 forward of the dense decoder family, and the check that
-decides ``correct``.
+"""Plain float32 forward of the dense decoder family (the ``reference`` of
+``bench/families/dense.py``), which ``bench/check.py`` holds served tokens
+against.
 
 Straight ``jax.numpy`` under ``default_matmul_precision("highest")``: no
 kernel, no cache, no batching, nothing imported from the program.  It
@@ -20,8 +21,6 @@ from __future__ import annotations
 import functools
 
 import numpy as np
-
-from bench.model_dims import Dims
 
 #: largest finite float8_e4m3fn value
 _FP8_MAX = 448.0
@@ -59,7 +58,7 @@ def _rope(x, theta):
 
 
 @functools.lru_cache(maxsize=None)
-def _layer_fn(m: Dims, fp8: bool):
+def _layer_fn(m, fp8: bool):
     import jax
     import jax.numpy as jnp
 
@@ -97,7 +96,7 @@ def _layer_fn(m: Dims, fp8: bool):
 
 
 @functools.lru_cache(maxsize=None)
-def _head_fn(m: Dims, fp8: bool):
+def _head_fn(m, fp8: bool):
     import jax
     import jax.numpy as jnp
 
@@ -124,11 +123,12 @@ def _embed_fn(fp8: bool):
     return jax.jit(embed)
 
 
-def logits(w: dict, m: Dims, tokens: np.ndarray, rows: np.ndarray,
+def logits(w: dict, m, tokens: np.ndarray, rows: np.ndarray,
            fp8: bool = False) -> np.ndarray:
     """float32 logits at positions ``rows`` of ``tokens`` (causal, so
-    padding the sequence changes nothing before it).  ``fp8`` rounds every
-    matrix through float8 first: the control."""
+    padding the sequence changes nothing before it); ``m`` is the dense
+    family's ``Dims``.  ``fp8`` rounds every matrix through float8 first:
+    the control."""
     import jax.numpy as jnp
     S = len(tokens)
     padded = np.zeros(-(-S // _PAD) * _PAD, np.int32)
@@ -139,19 +139,3 @@ def logits(w: dict, m: Dims, tokens: np.ndarray, rows: np.ndarray,
         x = layer(x, w, jnp.int32(i))
     out = _head_fn(m, fp8)(x, w, jnp.asarray(np.asarray(rows, np.int32)))
     return np.asarray(out, np.float32)
-
-
-def served_gaps(w: dict, m: Dims, prompt: np.ndarray, served,
-                control: bool = False) -> np.ndarray:
-    """For each served token, how far its float32 logit lies below the
-    reference's best at that position, given the prompt and the tokens
-    served before it.  With ``control`` the token is instead the one the
-    float8 control puts first at the same position."""
-    served = np.asarray(served, np.int32)
-    seq = np.concatenate([np.asarray(prompt, np.int32), served])
-    rows = np.arange(len(prompt) - 1, len(seq) - 1)
-    ref = logits(w, m, seq[:-1], rows)
-    pick = served
-    if control:
-        pick = logits(w, m, seq[:-1], rows, fp8=True).argmax(-1)
-    return ref.max(-1) - ref[np.arange(len(rows)), pick]

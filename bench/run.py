@@ -127,10 +127,9 @@ def measure(cell, seed: int, seconds: float, trace: bool, devices,
     faults with it)."""
     from bench import harness, trace_reduce
     from bench.check import sample
-    from bench.weights import make_weights, to_program
 
-    m = cell.dims
-    params = to_program(make_weights(m, seed, devices[0]), m)
+    params = harness.program_params(cell,
+                                    harness.weights(cell, seed, devices[0]))
     engine = harness.build_engine(cell, params)
     del params
     if engine_hook is not None:
@@ -174,9 +173,9 @@ def measure(cell, seed: int, seconds: float, trace: bool, devices,
 def check(cell, seed: int, rec, pairs, device) -> dict:
     """The numbers compared, each with its value and limit."""
     from bench.check import max_logit_gap
-    from bench.weights import make_weights
-    w = make_weights(cell.dims, seed, device)
-    gap, n = max_logit_gap(w, cell.dims, pairs)
+    from bench.harness import weights
+    gap, n = max_logit_gap(cell.family.reference,
+                           weights(cell, seed, device), cell.dims, pairs)
     return {
         "max_logit_gap": {"value": gap,
                           "limit": cell.config["check"]["max_logit_gap"]},

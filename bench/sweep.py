@@ -56,12 +56,10 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     run.prepare()
     from bench import harness
-    from bench.weights import make_weights, to_program
     cell = harness.load_cell(args.workload)
     devices = run.device_check(cell.chips)
-    m = cell.dims
-    engine = harness.build_engine(
-        cell, to_program(make_weights(m, args.seed, devices[0]), m))
+    engine = harness.build_engine(cell, harness.program_params(
+        cell, harness.weights(cell, args.seed, devices[0])))
     harness.warm_up(engine, cell, args.seed)
     for rate in (float(r) for r in args.rates.split(",")):
         c = dataclasses.replace(cell, traffic=dict(cell.traffic,

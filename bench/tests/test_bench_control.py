@@ -14,9 +14,10 @@ def _reduced(monkeypatch):
 
 
 def test_float8_control_fails_where_the_program_passes():
-    """Both sides judged by ``run.passed`` against the committed limit."""
+    """Both sides judged by ``run.passed`` against the family's limit for
+    the reduced size, which is no looser than the committed one."""
     cell = tiny.cell("starcoder2-3b.code_decode")
-    assert cell.config["check"]["max_logit_gap"] == \
+    assert cell.config["check"]["max_logit_gap"] < \
         tiny.full("starcoder2-3b.code_decode").config["check"]["max_logit_gap"]
     for r in control.readings(cell, [21, 2**33 + 22], 2.0, jax.devices()):
         assert r["tokens"] >= 150

@@ -114,11 +114,11 @@ def test_tpot_and_rate_cover_the_whole_window():
     assert rec.window_tokens == 15
     assert _read("output_tok_s", rec) == pytest.approx(30.0)
     assert _read("tpot_p95_ms", rec) == pytest.approx(100.0)
-    # decoded tokens are all but each request's first; token i of a
-    # 4-token prompt attends over 4 + i lines
-    assert rec.decode_tokens == 12
-    assert rec.decode_ctx == 3 * sum(4 + i for i in range(1, 5))
-    assert rec.prompt_tokens == 12 and rec.prompt_ctx == 3 * 10
+    # decoded tokens are all but each request's first, one a step; token
+    # i of a 4-token prompt attends over 4 + i lines
+    assert sorted(rec.decode_runs) == sorted([(4 + i, 1)
+                                              for i in range(1, 5)] * 3)
+    assert rec.prompt_runs == [(1, 4)] * 3
     assert _read("batch_fill", rec) == pytest.approx(15 / (5 * 8))
 
 

@@ -1,9 +1,11 @@
 """Cells at the size of the program's ``reduced()`` presets, for CPU tests:
-the same configuration and traffic files with every size cut down, and
-the program's configuration swapped for its reduced twin."""
+the same configuration and traffic files with every size cut down (the
+model's by its family's ``tiny``), and the program's configuration
+swapped for its reduced twin."""
 from __future__ import annotations
 
 import copy
+import dataclasses
 import json
 
 from bench import harness
@@ -22,24 +24,24 @@ def full(name: str) -> harness.Cell:
     return harness.cell_of(name, harness.BENCH / "configs" / f"{config}.json",
                            traffic, 1, bench)
 
-#: reduced() sizes of both architectures (2 layers, 256 wide, vocab 512)
-_SIZES = {"hidden_size": 256, "intermediate_size": 512,
-          "num_hidden_layers": 2, "num_attention_heads": 4,
-          "vocab_size": 512}
-_KV = {"stablelm-3b": 4, "starcoder2-3b": 2}
+
 ENGINE = {"slots": 4, "cache_len": 128, "page_size": 16, "kv_pages": 25,
           "max_batch_tokens": 32, "decode_chunk": 4, "use_pallas": False}
 
 
 def cell(name: str) -> harness.Cell:
-    c = copy.deepcopy(full(name))
-    arch = c.config["arch"]
-    c.config.update(_SIZES, num_key_value_heads=_KV[arch])
-    c.config["engine"].update(ENGINE)
-    # the committed limit stands; about 200 served tokens are compared,
-    # so that the float8 control meets a near tie (PERF.md)
-    c.config["check"].update(sample_tokens=192)
-    t = c.traffic
+    return shrink(full(name))
+
+
+def shrink(c: harness.Cell) -> harness.Cell:
+    """``c`` with its configuration cut to its family's ``tiny`` sizes and
+    its engine and traffic to a few seconds' work on the CPU."""
+    config, t = copy.deepcopy(c.config), copy.deepcopy(c.traffic)
+    config.update(c.family.tiny(config))
+    config["engine"].update(ENGINE)
+    # the family's limit for its reduced size stands; about 200 served
+    # tokens are compared, so that the float8 control meets a near tie
+    config["check"].update(sample_tokens=192)
     t["prompt_tokens"] = {"median": 12, "sigma": 0.5, "min": 4, "max": 24}
     t["output_tokens"] = {"median": 24, "sigma": 0.5, "min": 8, "max": 48}
     if "system_prompts" in t:
@@ -48,7 +50,7 @@ def cell(name: str) -> harness.Cell:
     if "streams" in t:
         t["streams"], t["requests_per_stream"] = 3, 6
     t["drain_s"] = 30
-    return c
+    return dataclasses.replace(c, config=config, traffic=t)
 
 
 def use_reduced_program(monkeypatch) -> None:

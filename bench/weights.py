@@ -1,43 +1,16 @@
 """Random weights from the seed, made on the device in one jitted call.
 
-The benchmark owns the weights: it makes them, hands them to the system
-under test in the program's parameter layout, and makes them again for
-the plain reference after the window.  Every leaf is drawn in bfloat16,
-the type it is served in, from ``fold_in(key(seed), leaf number)``.
-Norm scales are drawn around 1 and biases around 0, so the comparison
-with the reference covers them too.
+The benchmark owns the weights: it makes them in the layout of the
+configuration's family (``bench/families/<family>.py``), hands them to the
+system under test through the family's ``to_program``, and makes them
+again for the plain reference after the window.  Every leaf is drawn in
+bfloat16, the type it is served in, from ``fold_in(key(seed), leaf
+number)``.  Norm scales are drawn around 1 and biases around 0, so the
+comparison with the reference covers them too.
 """
 from __future__ import annotations
 
 import numpy as np
-
-from bench.model_dims import Dims
-
-
-def layout(m: Dims) -> dict:
-    """name -> (shape, mean, std).  Layer leaves carry a leading layer
-    axis."""
-    d, f, L, H, K, Dh, V = m.d, m.f, m.L, m.H, m.K, m.Dh, m.V
-    out = {
-        "embed": ((V, d), 0.0, d ** -0.5),
-        "norm1": ((L, d), 1.0, 0.1),
-        "wq": ((L, d, H, Dh), 0.0, d ** -0.5),
-        "wk": ((L, d, K, Dh), 0.0, d ** -0.5),
-        "wv": ((L, d, K, Dh), 0.0, d ** -0.5),
-        "wo": ((L, H, Dh, d), 0.0, (H * Dh) ** -0.5),
-        "norm2": ((L, d), 1.0, 0.1),
-        "w1": ((L, d, f), 0.0, d ** -0.5),
-        "w2": ((L, f, d), 0.0, f ** -0.5),
-        "final_norm": ((d,), 1.0, 0.1),
-        "lm_head": ((V, d), 0.0, d ** -0.5),
-    }
-    if m.mlp == "swiglu":
-        out["w3"] = ((L, d, f), 0.0, d ** -0.5)
-    if m.qkv_bias:
-        out["bq"] = ((L, H, Dh), 0.0, 0.1)
-        out["bk"] = ((L, K, Dh), 0.0, 0.1)
-        out["bv"] = ((L, K, Dh), 0.0, 0.1)
-    return out
 
 
 def seed_words(seed: int) -> np.ndarray:
@@ -46,12 +19,13 @@ def seed_words(seed: int) -> np.ndarray:
     return np.array([a >> 1, b >> 1], np.int32)
 
 
-def make_weights(m: Dims, seed: int, device=None) -> dict:
-    """Every leaf of ``layout(m)`` in bfloat16, in one jitted call."""
+def make_weights(spec: dict, seed: int, device=None) -> dict:
+    """Every leaf of ``spec`` (a family's ``layout``: name -> (shape,
+    mean, std)) in bfloat16, in one jitted call; leaf i of the sorted
+    names is drawn from ``fold_in(key, i)``."""
     import jax
     import jax.numpy as jnp
 
-    spec = layout(m)
     names = sorted(spec)
 
     def gen(words):
@@ -68,16 +42,3 @@ def make_weights(m: Dims, seed: int, device=None) -> dict:
     if device is not None:
         words = jax.device_put(words, device)
     return jax.jit(gen)(words)
-
-
-def to_program(w: dict, m: Dims) -> dict:
-    """The same arrays arranged as ``repro.models`` expects its
-    parameters (one stacked layer group; no copies)."""
-    attn = {k: w[k] for k in ("wq", "wk", "wv", "wo", "bq", "bk", "bv")
-            if k in w}
-    mlp = {k: w[k] for k in ("w1", "w2", "w3") if k in w}
-    layer = {"norm1": {"scale": w["norm1"]}, "attn": attn,
-             "norm2": {"scale": w["norm2"]}, "mlp": mlp}
-    return {"embed": {"tok": w["embed"]}, "layers": [layer],
-            "final_norm": {"scale": w["final_norm"]},
-            "lm_head": {"w": w["lm_head"]}}
