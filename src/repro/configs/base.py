@@ -24,6 +24,21 @@ class MoEConfig:
     group_size: int = 1024        # GShard dispatch group size (tokens)
     router_z_coef: float = 1e-3   # router z-loss
     balance_coef: float = 1e-2    # load-balance aux loss
+    # routing at inference (``models.moe.moe_dropless``): scores are the
+    # softmax or the sigmoid of the float32 router logits; a selection-only
+    # per-expert bias (``expert_bias``) may shift which k are chosen; the
+    # chosen scores are renormalised over the k and scaled
+    first_dense: int = 0          # leading layers with a dense FFN (d_ff)
+    score_func: str = "softmax"   # softmax | sigmoid
+    route_scale: float = 1.0
+    expert_bias: bool = False
+    # this chip's share of each expert layer: experts [first, first + n)
+    # of ``num_experts``; None holds every expert
+    experts_held: Optional[tuple[int, int]] = None
+
+    def held(self) -> tuple[int, int]:
+        """(first expert held, number held)."""
+        return self.experts_held or (0, self.num_experts)
 
 
 @dataclass(frozen=True)
@@ -58,6 +73,15 @@ class ModelConfig:
     qkv_bias: bool = False
     pos_embedding: str = "rope"   # rope | sinusoidal
     sliding_window: Optional[int] = None  # None = full causal
+    # per-layer attention kinds, one per layer: "sliding_attention" layers
+    # take ``sliding_window``, "full_attention" layers none; None makes
+    # every layer as ``sliding_window`` says
+    layer_types: Optional[tuple[str, ...]] = None
+    rope_full_layers: bool = True  # False: rotary on sliding layers only
+    qk_norm: bool = False         # RMSNorm of q and k per head
+    attn_gate: bool = False       # attention output * sigmoid(x Wg)
+    sandwich_norm: bool = False   # RMSNorm of each sublayer's output too
+    embed_scale: bool = False     # embeddings * sqrt(d_model) (muP)
     # FFN
     mlp_type: str = "swiglu"      # swiglu | gelu | relu2
     # mixer schedule (hybrid): 1 attention layer per `attn_every` layers,
@@ -89,9 +113,20 @@ class ModelConfig:
         if self.moe is None:
             return ["dense"] * self.num_layers
         return [
-            "moe" if (i % self.moe.every == self.moe.every - 1) else "dense"
+            "moe" if (i % self.moe.every == self.moe.every - 1
+                      and i >= self.moe.first_dense) else "dense"
             for i in range(self.num_layers)
         ]
+
+    def attn_kinds(self) -> list[tuple[Optional[int], bool]]:
+        """(window or None, rotary or not) of each layer's attention."""
+        rope = self.pos_embedding == "rope"
+        if self.layer_types is None:
+            return [(self.sliding_window, rope)] * self.num_layers
+        assert len(self.layer_types) == self.num_layers, self.layer_types
+        return [(self.sliding_window, rope) if t == "sliding_attention"
+                else (None, rope and self.rope_full_layers)
+                for t in self.layer_types]
 
     def with_sliding_window(self, window: int) -> "ModelConfig":
         return replace(self, sliding_window=window)
@@ -173,6 +208,7 @@ ARCH_IDS = [
     "mamba2-780m",
     "dbrx-132b",
     "minitron-4b",
+    "trinity-mini",
 ]
 
 

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.actshard import constrain, maybe_psum, tp_will_reduce
+from repro.models.mlp import rmsnorm
 
 _NEG_INF = -1e30
 
@@ -63,7 +64,21 @@ def qkv_proj(p: dict, x: jax.Array, cfg: ModelConfig):
         q = q + p["bq"].astype(dtype)
         k = k + p["bk"].astype(dtype)
         v = v + p["bv"].astype(dtype)
+    if cfg.qk_norm:                   # RMSNorm over each head's Dh
+        q = rmsnorm(q, p["q_norm"]["scale"], cfg.norm_eps)
+        k = rmsnorm(k, p["k_norm"]["scale"], cfg.norm_eps)
     return (constrain(q, "heads"), constrain(k, "kv"), constrain(v, "kv"))
+
+
+def gate_heads(p: dict, x: jax.Array, o: jax.Array, cfg: ModelConfig):
+    """o: (B, S, H, Dh) attention output of the sublayer input x; with
+    ``cfg.attn_gate`` each head's lines are scaled by sigmoid(x Wg)."""
+    if not cfg.attn_gate:
+        return o
+    g = jnp.einsum("bsd,dhk->bshk", x, p["wg"].astype(x.dtype),
+                   preferred_element_type=jnp.float32)
+    return constrain((o.astype(jnp.float32) * jax.nn.sigmoid(g))
+                     .astype(o.dtype), "heads")
 
 
 def out_proj(p: dict, o: jax.Array) -> jax.Array:
@@ -185,7 +200,8 @@ def attention_train(p: dict, x: jax.Array, cfg: ModelConfig,
     else:
         o = causal_attention(q, k, v, cfg, unroll=unroll,
                              one_block=one_block)
-    return out_proj(p, constrain(o, "heads"))
+    o = gate_heads(p, x, constrain(o, "heads"), cfg)
+    return out_proj(p, o)
 
 
 # ---------------------------------------------------------------- decode ----
@@ -277,6 +293,7 @@ def attention_prefill(p: dict, x: jax.Array, cfg: ModelConfig,
         idx = (jnp.arange(S - cache_slots, S)) % cache_slots
         ck = jnp.zeros_like(tail_k).at[:, idx].set(tail_k)
         cv = jnp.zeros_like(tail_v).at[:, idx].set(tail_v)
+    o = gate_heads(p, x, o, cfg)
     return out_proj(p, o), {"k": ck, "v": cv}
 
 
@@ -381,7 +398,8 @@ def attention_prefill_paged(p: dict, x: jax.Array, cache: dict,
          + jnp.einsum("bkgqs,bskd->bqkgd", probs[..., L:].astype(f32),
                       v.astype(f32)))
     o = o.astype(x.dtype).reshape(B, S, H, Dh)
-    return out_proj(p, constrain(o, "heads")), {"k": k, "v": v}
+    o = gate_heads(p, x, constrain(o, "heads"), cfg)
+    return out_proj(p, o), {"k": k, "v": v}
 
 
 def attention_verify(p: dict, x: jax.Array, cache: dict, layer,
@@ -435,7 +453,8 @@ def attention_verify(p: dict, x: jax.Array, cache: dict, layer,
     logits = jnp.where(valid[:, None, None], logits, _NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
     o = jnp.einsum("bkgqs,bskd->bqkgd", probs, vd).reshape(B, S, H, Dh)
-    return out_proj(p, constrain(o, "heads")), {"k": ck, "v": cv}
+    o = gate_heads(p, x, constrain(o, "heads"), cfg)
+    return out_proj(p, o), {"k": ck, "v": cv}
 
 
 def attention_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array,
@@ -468,7 +487,8 @@ def attention_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array,
     if use_pallas:
         from repro.kernels import ops as kops
         o = kops.flash_decode(q, ck, cv, posv, window=cfg.sliding_window)
-        return out_proj(p, constrain(o, "heads")), {"k": ck, "v": cv}
+        o = gate_heads(p, x, constrain(o, "heads"), cfg)
+        return out_proj(p, o), {"k": ck, "v": cv}
 
     H, Dh = q.shape[2], q.shape[3]
     K = ck.shape[2]
@@ -485,7 +505,8 @@ def attention_decode(p: dict, x: jax.Array, cache: dict, pos: jax.Array,
     logits = jnp.where(valid[:, None, None, None, :], logits, _NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
     o = jnp.einsum("bkgqs,bskd->bqkgd", probs, cv).reshape(B, 1, H, Dh)
-    return out_proj(p, constrain(o, "heads")), {"k": ck, "v": cv}
+    o = gate_heads(p, x, constrain(o, "heads"), cfg)
+    return out_proj(p, o), {"k": ck, "v": cv}
 
 
 def attention_decode_paged(p: dict, x: jax.Array, cache: dict, layer,
@@ -535,7 +556,8 @@ def attention_decode_paged(p: dict, x: jax.Array, cache: dict, layer,
     if use_pallas:
         from repro.kernels import ops as kops
         o = kops.flash_decode_paged(q, ck, cv, page_table, posv, layer)
-        return out_proj(p, constrain(o, "heads")), {"k": ck, "v": cv}
+        o = gate_heads(p, x, constrain(o, "heads"), cfg)
+        return out_proj(p, o), {"k": ck, "v": cv}
 
     H, Dh = q.shape[2], q.shape[3]
     K = ck.shape[2]
@@ -550,4 +572,5 @@ def attention_decode_paged(p: dict, x: jax.Array, cache: dict, layer,
     logits = jnp.where(valid[:, None, None, None, :], logits, _NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
     o = jnp.einsum("bkgqs,bskd->bqkgd", probs, vd).reshape(B, 1, H, Dh)
-    return out_proj(p, constrain(o, "heads")), {"k": ck, "v": cv}
+    o = gate_heads(p, x, constrain(o, "heads"), cfg)
+    return out_proj(p, o), {"k": ck, "v": cv}

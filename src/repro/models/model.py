@@ -18,6 +18,7 @@ carries precomputed prefix/frame embeddings and this module consumes them.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional
 
@@ -43,7 +44,10 @@ def compute_dtype(cfg: ModelConfig):
 
 def embed_tokens(params, tokens, cfg: ModelConfig):
     tab = params["embed"]["tok"].astype(compute_dtype(cfg))
-    return jnp.take(tab, tokens, axis=0)
+    h = jnp.take(tab, tokens, axis=0)
+    if cfg.embed_scale:                               # muP: x sqrt(d)
+        h = h * jnp.asarray(cfg.d_model ** 0.5, h.dtype)
+    return h
 
 
 def build_hidden(params, batch: dict, cfg: ModelConfig):
@@ -75,17 +79,41 @@ def _zeros_aux():
     return {k: jnp.zeros((), jnp.float32) for k in AUX_KEYS}
 
 
+def layer_config(cfg: ModelConfig, i: int, paged: bool = False):
+    """``cfg`` as the attention of layer slot ``i`` sees it: its own
+    window and rotary (``ModelConfig.attn_kinds``).  ``paged`` drops every
+    window: the paged pool holds no ring, and ``DecodeEngine`` pages a
+    windowed configuration only where its cache cannot outgrow the window,
+    so there the window changes nothing."""
+    if cfg.layer_types is None and not (paged and cfg.sliding_window):
+        return cfg
+    window, rope = cfg.attn_kinds()[i]
+    return dataclasses.replace(
+        cfg, layer_types=None, sliding_window=None if paged else window,
+        pos_embedding=cfg.pos_embedding if rope or cfg.pos_embedding != "rope"
+        else "none")
+
+
+def _post_norm(p, name: str, h, cfg: ModelConfig):
+    """A sublayer's output, through its own RMSNorm under
+    ``cfg.sandwich_norm``."""
+    if not cfg.sandwich_norm:
+        return h
+    return rmsnorm(h, p[name]["scale"], cfg.norm_eps)
+
+
 def sublayer_train(p, x, mixer: str, ffn: str, cfg: ModelConfig,
-                   run: RunConfig):
+                   run: RunConfig, acfg: Optional[ModelConfig] = None):
+    """One sublayer; ``acfg`` is its attention's ``layer_config``."""
     aux = _zeros_aux()
     h = rmsnorm(x, p["norm1"]["scale"], cfg.norm_eps)
     if mixer == "attn":
-        h = A.attention_train(p["attn"], h, cfg, use_pallas=run.use_pallas,
-                              unroll=run.unroll,
+        h = A.attention_train(p["attn"], h, acfg or cfg,
+                              use_pallas=run.use_pallas, unroll=run.unroll,
                               one_block=run.seq_parallel)
     else:
         h = SSM.ssm_train(p["ssm"], h, cfg, use_pallas=run.use_pallas)
-    x = constrain(x + h, "hidden")
+    x = _attn_block(p, x, h, cfg)
     if ffn != "none":
         h = rmsnorm(x, p["norm2"]["scale"], cfg.norm_eps)
         if run.seq_parallel:
@@ -101,53 +129,80 @@ def sublayer_train(p, x, mixer: str, ffn: str, cfg: ModelConfig,
             h = mlp_apply(p["mlp"], h, cfg.mlp_type)
         if run.seq_parallel:
             h = constrain(h, "hidden")
-        x = constrain(x + h, "hidden")
+        x = constrain(x + _post_norm(p, "post_mlp_norm", h, cfg), "hidden")
     return x, aux
 
 
-def _ffn_block(p, x, ffn: str, cfg: ModelConfig):
-    """The sublayer's FFN half (norm, MLP or MoE, residual) at inference."""
+def _attn_block(p, x, hh, cfg: ModelConfig):
+    """The attention half's residual: x + (normed) attention output."""
+    return constrain(x + _post_norm(p, "post_attn_norm", hh, cfg), "hidden")
+
+
+def _ffn_block(p, x, ffn: str, cfg: ModelConfig, use_pallas: bool = False,
+               valid=None, name: str = "moe_experts"):
+    """The sublayer's FFN half (norm, MLP or dropless MoE, residual) at
+    inference.  Returns (x, the expert layer's ``MOE.MOE_STATS`` counts of
+    the rows ``valid`` marks, or None); ``name`` names its kernels."""
     if ffn == "none":
-        return x
+        return x, None
     hh = rmsnorm(x, p["norm2"]["scale"], cfg.norm_eps)
+    stats = None
     if ffn == "moe":
-        hh, _ = MOE.moe_apply(p["moe"], hh, cfg)
+        hh, stats = MOE.moe_dropless(p["moe"], hh, cfg, use_pallas, valid,
+                                     name)
     else:
         hh = mlp_apply(p["mlp"], hh, cfg.mlp_type)
-    return constrain(x + hh, "hidden")
+    return constrain(x + _post_norm(p, "post_mlp_norm", hh, cfg),
+                     "hidden"), stats
 
 
 def _paged_layers(params, cache, x, attend, cfg: ModelConfig,
-                  run: RunConfig, remat: bool = False):
+                  run: RunConfig, remat: bool = False, count=None,
+                  name: str = "moe_experts"):
     """Run the layer groups against the stacked paged pools.
 
-    ``attend(p_attn, h, pool, g) -> (h, pool, out)`` is one attention
-    sublayer: ``pool`` is that sublayer's ``{"k", "v"}`` pool, each leaf
-    ``(G, num_pages, K, page_size, Dh)``, and ``g`` the int32 group index
-    it reads and writes at.  The pools ride whole in the loop's carry,
-    never in ``xs``/``ys``: no layer's pool is sliced out and stacked
-    back, so a layer's new lines are written in place and its pages are
-    read where they lie.  Returns (x, {"layers": [...]} pools, ``out``s
-    stacked over groups, a tuple per sublayer)."""
+    ``attend(p_attn, h, pool, g, acfg) -> (h, pool, out)`` is one
+    attention sublayer: ``pool`` is that sublayer's ``{"k", "v"}`` pool,
+    each leaf ``(G, num_pages, K, page_size, Dh)``, ``g`` the int32 group
+    index it reads and writes at, and ``acfg`` its ``layer_config``.  The
+    pools ride whole in the loop's carry, never in ``xs``/``ys``: no
+    layer's pool is sliced out and stacked back, so a layer's new lines
+    are written in place and its pages are read where they lie.  A cache
+    that carries ``"moe_stats"`` ((4,) int32, ``MOE.MOE_STATS``) carries
+    it through; where ``count`` ((B, S) bool: the rows to count) is given,
+    every expert layer's counts of those rows are added to it.  ``name``
+    names the expert kernels (``MOE.moe_dropless``).  Returns (x,
+    {"layers": [...]} pools (and the counts), ``out``s stacked over
+    groups, a tuple per sublayer)."""
     sched = layer_schedule(cfg)[:group_period(cfg)]
     assert all(mixer == "attn" for mixer, _ in sched), \
         "the paged KV pool is full-attention only"
+    counting = "moe_stats" in cache
+    adding = counting and count is not None
 
     def step(carry, inp):
-        x, pools = carry
+        x, pools = carry[:2]
+        stats = carry[2] if counting else None
         group_params, g = inp
         pools, outs = list(pools), []
         for i, (_mixer, ffn) in enumerate(sched):
             p = group_params[i]
             hh = rmsnorm(x, p["norm1"]["scale"], cfg.norm_eps)
-            hh, pools[i], out = attend(p["attn"], hh, pools[i], g)
-            x = _ffn_block(p, constrain(x + hh, "hidden"), ffn, cfg)
+            hh, pools[i], out = attend(p["attn"], hh, pools[i], g,
+                                       layer_config(cfg, i, paged=True))
+            x, st = _ffn_block(p, _attn_block(p, x, hh, cfg), ffn, cfg,
+                               run.use_pallas, count, name)
+            if adding and st is not None:
+                stats = stats + st
             outs.append(out)
-        return (x, tuple(pools)), tuple(outs)
+        new = (x, tuple(pools)) + ((stats,) if counting else ())
+        return new, tuple(outs)
 
     if remat:
         step = jax.checkpoint(step, prevent_cse=False)
     carry = (x, tuple(cache["layers"]))
+    if counting:
+        carry += (cache["moe_stats"],)
     n_groups = jax.tree.leaves(params["layers"])[0].shape[0]
     if run.unroll:
         per_group = []
@@ -160,8 +215,10 @@ def _paged_layers(params, cache, x, attend, cfg: ModelConfig,
         carry, outs = jax.lax.scan(
             step, carry,
             (tuple(params["layers"]), jnp.arange(n_groups, dtype=jnp.int32)))
-    x, pools = carry
-    return x, {"layers": list(pools)}, outs
+    new_cache = {"layers": list(carry[1])}
+    if counting:
+        new_cache["moe_stats"] = carry[2]
+    return carry[0], new_cache, outs
 
 
 # --------------------------------------------------------------- forward ----
@@ -174,7 +231,8 @@ def backbone_train(params, h, cfg: ModelConfig, run: RunConfig):
     def group_body(carry, group_params):
         x, acc = carry
         for i, (mixer, ffn) in enumerate(sched):
-            x, aux = sublayer_train(group_params[i], x, mixer, ffn, cfg, run)
+            x, aux = sublayer_train(group_params[i], x, mixer, ffn, cfg, run,
+                                    layer_config(cfg, i))
             acc = {k: acc[k] + aux[k] for k in AUX_KEYS}
         return (x, acc), None
 
@@ -241,9 +299,10 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int,
     n_groups = cfg.num_layers // P
     sched = layer_schedule(cfg)[:P]
     layers = []
-    for mixer, _ in sched:
+    for i, (mixer, _) in enumerate(sched):
         if mixer == "attn":
-            layers.append(A.init_kv_cache(cfg, batch, cache_len, n_groups,
+            layers.append(A.init_kv_cache(layer_config(cfg, i), batch,
+                                          cache_len, n_groups,
                                           abstract=abstract, paging=paging))
         else:
             assert paging is None, "paged KV cache is attention-only"
@@ -328,7 +387,6 @@ def prefill(params, batch: dict, cfg: ModelConfig, run: RunConfig,
     h = build_hidden(params, batch, cfg)
     S = h.shape[1]
     cache_len = cache_len or S
-    slots = min(cache_len, cfg.sliding_window or cache_len)
     positions = valid = None
     if front_pad is not None:
         assert num_real is not None
@@ -345,7 +403,9 @@ def prefill(params, batch: dict, cfg: ModelConfig, run: RunConfig,
             p = group_params[i]
             hh = rmsnorm(x, p["norm1"]["scale"], cfg.norm_eps)
             if mixer == "attn":
-                hh, c = A.attention_prefill(p["attn"], hh, cfg, slots,
+                acfg = layer_config(cfg, i)
+                slots = min(cache_len, acfg.sliding_window or cache_len)
+                hh, c = A.attention_prefill(p["attn"], hh, acfg, slots,
                                             use_pallas=run.use_pallas,
                                             unroll=run.unroll,
                                             positions=positions,
@@ -356,7 +416,8 @@ def prefill(params, batch: dict, cfg: ModelConfig, run: RunConfig,
                     valid=valid,
                     conv_end=(None if front_pad is None
                               else front_pad + num_real))
-            x = _ffn_block(p, constrain(x + hh, "hidden"), ffn, cfg)
+            x, _ = _ffn_block(p, _attn_block(p, x, hh, cfg), ffn, cfg,
+                              run.use_pallas, name="moe_experts_prefill")
             new_caches.append(c)
         return x, tuple(new_caches)
 
@@ -411,19 +472,21 @@ def prefill_suffix(params, batch: dict, cache, page_table, start,
         h = h + pe[None].astype(h.dtype)
     h = constrain(h, "hidden")
 
-    def attend(p, hh, pool, g):
+    def attend(p, hh, pool, g, acfg):
         hh, c = A.attention_prefill_paged(p, hh, pool, g, page_table,
-                                          start, cfg)
+                                          start, acfg)
         return hh, pool, c
 
-    h, _, caches = _paged_layers(params, cache, h, attend, cfg, run,
-                                 remat=run.remat in ("layer", "full"))
+    h, new_cache, caches = _paged_layers(
+        params, cache, h, attend, cfg, run,
+        remat=run.remat in ("layer", "full"), name="moe_experts_prefill")
     h = rmsnorm(h, params["final_norm"]["scale"], cfg.norm_eps)
     if last_pos is None:
         h_last = h[:, -1:]
     else:
         h_last = jax.lax.dynamic_slice_in_dim(h, last_pos, 1, axis=1)
-    return unembed(params, h_last, cfg), {"layers": list(caches)}
+    return unembed(params, h_last, cfg), dict(new_cache,
+                                              layers=list(caches))
 
 
 def prefill_chunk(params, batch: dict, cache, page_table, start,
@@ -467,14 +530,16 @@ def prefill_chunk(params, batch: dict, cache, page_table, start,
 # ----------------------------------------------------------------- decode ----
 
 def decode_step(params, cache, token, pos, cfg: ModelConfig, run: RunConfig,
-                page_table=None):
+                page_table=None, done=None):
     """One decoding step.  token: (B, 1) int32; pos: scalar int32 OR (B,)
     int32 (0-based absolute position of each new token — vector form for
     continuous batching).  ``page_table`` ((B, n_pages) int32) routes
     attention through the paged KV pool instead of dense per-slot rows:
     the stacked pool is carried through the layer loop, each layer writes
     its B new lines in place and reads its pages where they lie
-    (:func:`_paged_layers`).  Returns (logits (B,1,V), new cache)."""
+    (:func:`_paged_layers`); there the expert counters count the lanes
+    not ``done`` ((B,) bool, default none).  Returns (logits (B,1,V),
+    new cache)."""
     P = group_period(cfg)
     sched = layer_schedule(cfg)[:P]
     B = token.shape[0]
@@ -485,13 +550,18 @@ def decode_step(params, cache, token, pos, cfg: ModelConfig, run: RunConfig,
         h = h + pe.astype(h.dtype)
 
     if page_table is not None:
-        def attend(p, hh, pool, g):
+        def attend(p, hh, pool, g, acfg):
             hh, pool = A.attention_decode_paged(
-                p, hh, pool, g, pos, page_table, cfg,
+                p, hh, pool, g, pos, page_table, acfg,
                 use_pallas=run.use_pallas)
             return hh, pool, None
 
-        h, new_cache, _ = _paged_layers(params, cache, h, attend, cfg, run)
+        count = None
+        if "moe_stats" in cache:
+            count = (jnp.ones((B, 1), bool) if done is None
+                     else jnp.logical_not(done).reshape(B, 1))
+        h, new_cache, _ = _paged_layers(params, cache, h, attend, cfg, run,
+                                        count=count)
         h = rmsnorm(h, params["final_norm"]["scale"], cfg.norm_eps)
         return unembed(params, h, cfg), new_cache
 
@@ -503,11 +573,12 @@ def decode_step(params, cache, token, pos, cfg: ModelConfig, run: RunConfig,
             hh = rmsnorm(x, p["norm1"]["scale"], cfg.norm_eps)
             if mixer == "attn":
                 hh, c = A.attention_decode(p["attn"], hh, group_cache[i],
-                                           pos, cfg,
+                                           pos, layer_config(cfg, i),
                                            use_pallas=run.use_pallas)
             else:
                 hh, c = SSM.ssm_decode(p["ssm"], hh, group_cache[i], cfg)
-            x = _ffn_block(p, constrain(x + hh, "hidden"), ffn, cfg)
+            x, _ = _ffn_block(p, _attn_block(p, x, hh, cfg), ffn, cfg,
+                              run.use_pallas)
             new_caches.append(c)
         return x, tuple(new_caches)
 
@@ -561,12 +632,13 @@ def verify_tokens(params, cache, tokens, pos0, pages, offs, page_table,
         h = h + pe.astype(h.dtype)
     h = constrain(h, "hidden")
 
-    def attend(p, hh, pool, g):
+    def attend(p, hh, pool, g, acfg):
         hh, pool = A.attention_verify(p, hh, pool, g, pos0, pages, offs,
-                                      page_table, cfg)
+                                      page_table, acfg)
         return hh, pool, None
 
-    h, new_cache, _ = _paged_layers(params, cache, h, attend, cfg, run)
+    h, new_cache, _ = _paged_layers(params, cache, h, attend, cfg, run,
+                                    name="moe_experts_verify")
     h = rmsnorm(h, params["final_norm"]["scale"], cfg.norm_eps)
     return unembed(params, h, cfg), new_cache
 
@@ -630,7 +702,8 @@ def decode_n(params, cache, token, pos, remaining, done, eos, temps, key,
     def body(carry, _):
         cache, tok, pos, rem, done, key = carry
         logits, cache = decode_step(params, cache, tok[:, None], pos, cfg,
-                                    run, page_table=page_table)
+                                    run, page_table=page_table,
+                                    done=done)
         key, sub = jax.random.split(key)
         nxt = sample_tokens(sub, logits[:, 0], temps)
         live = jnp.logical_not(done)

@@ -6,7 +6,9 @@ A spec tree mirrors the parameter pytree; each leaf is a :class:`ParamSpec`.
 ``repro.core.sharding`` maps the logical axes onto mesh axes per parallelism
 strategy — so model code never mentions mesh axes directly.
 
-Layer stacking: layers repeat with period ``P = lcm(attn_every, moe.every)``;
+Layer stacking: layers repeat with the period ``P`` of their (mixer, FFN,
+attention kind) schedule (``lcm(attn_every, moe.every)`` for uniform
+attention and no leading dense layers, else up to the whole depth);
 parameters of the P sublayers are stacked with a leading ``layers`` axis of
 size ``num_layers // P`` and the forward pass is a ``lax.scan`` over groups
 (compile time stays flat in depth).
@@ -70,6 +72,12 @@ def attention_spec(cfg: ModelConfig) -> dict:
         spec["bq"] = ParamSpec((H, Dh), ("heads", "head_dim"), "zeros")
         spec["bk"] = ParamSpec((K, Dh), ("kv_heads", "head_dim"), "zeros")
         spec["bv"] = ParamSpec((K, Dh), ("kv_heads", "head_dim"), "zeros")
+    if cfg.qk_norm:
+        spec["q_norm"] = {"scale": ParamSpec((Dh,), ("head_dim",), "ones")}
+        spec["k_norm"] = {"scale": ParamSpec((Dh,), ("head_dim",), "ones")}
+    if cfg.attn_gate:
+        spec["wg"] = ParamSpec((d, H, Dh), ("embed", "heads", "head_dim"),
+                               scale=s)
     return spec
 
 
@@ -114,10 +122,12 @@ def mlp_spec(d: int, f: int, mlp_type: str) -> dict:
 
 def moe_spec(cfg: ModelConfig) -> dict:
     moe = cfg.moe
-    d, E, f = cfg.d_model, moe.num_experts, moe.d_ff
+    d, f = cfg.d_model, moe.d_ff
+    E = moe.held()[1]                 # the router still scores all experts
     s_in, s_out = d ** -0.5, f ** -0.5
     spec = {
-        "router": ParamSpec((d, E), ("embed", None), scale=s_in),
+        "router": ParamSpec((d, moe.num_experts), ("embed", None),
+                            scale=s_in),
         "w1": ParamSpec((E, d, f), ("experts", "embed", "ffn"), scale=s_in),
         "w2": ParamSpec((E, f, d), ("experts", "ffn", "embed"), scale=s_out),
     }
@@ -126,6 +136,8 @@ def moe_spec(cfg: ModelConfig) -> dict:
                                scale=s_in)
     if moe.num_shared:
         spec["shared"] = mlp_spec(d, f * moe.num_shared, cfg.mlp_type)
+    if moe.expert_bias:
+        spec["expert_bias"] = ParamSpec((moe.num_experts,), (None,), "zeros")
     return spec
 
 
@@ -136,8 +148,12 @@ def sublayer_spec(cfg: ModelConfig, mixer: str, ffn: str) -> dict:
         spec["attn"] = attention_spec(cfg)
     else:
         spec["ssm"] = ssm_spec(cfg)
+    if cfg.sandwich_norm:
+        spec["post_attn_norm"] = _norm(d)
     if ffn != "none":
         spec["norm2"] = _norm(d)
+        if cfg.sandwich_norm:
+            spec["post_mlp_norm"] = _norm(d)
         if ffn == "moe":
             spec["moe"] = moe_spec(cfg)
         else:
@@ -160,7 +176,8 @@ def layer_schedule(cfg: ModelConfig) -> list[tuple[str, str]]:
 
 
 def group_period(cfg: ModelConfig) -> int:
-    sched = layer_schedule(cfg)
+    """Smallest period of the layers' (mixer, ffn, attention kind)."""
+    sched = list(zip(layer_schedule(cfg), cfg.attn_kinds()))
     for p in range(1, len(sched) + 1):
         if len(sched) % p == 0 and all(
             sched[i] == sched[i % p] for i in range(len(sched))

@@ -99,6 +99,7 @@ from jax.sharding import PartitionSpec as PSpec
 from repro.configs.base import ModelConfig, RunConfig
 from repro.models import init_cache, prefill
 from repro.models.attention import write_lines, write_pages
+from repro.models.moe import MOE_STATS
 from repro.models.model import (
     decode_n, decode_step, prefill_chunk, prefill_suffix, verify_tokens,
 )
@@ -236,6 +237,11 @@ class DecodeEngine:
         self.decode_chunk = max(1, int(decode_chunk))
         self.fused = fused
         self.paging = self._resolve_paging(kv_page_size, kv_pages)
+        if cfg.layer_types is not None and self.paging is None:
+            raise ValueError(
+                "cfg.layer_types: per-layer attention kinds serve on the "
+                "paged path only (kv_page_size > 0); the classic cache "
+                "sizes one ring for every layer")
         # the engine's cache (the paged pool, or dense rows) and the
         # one-request slices scattered into it carry kv heads at their
         # own dims, so each gets its own spec
@@ -292,6 +298,12 @@ class DecodeEngine:
                         self._resume_tokens(r))))
         self.cache = init_cache(cfg, num_slots, cache_len,
                                 paging=self.paging)
+        if cfg.moe is not None and self.paging is not None \
+                and not self.tp.active:
+            # the expert layers' counters ride in the cache pytree, which
+            # every paged program carries and returns: they add up on the
+            # device and are read only with ``serve_stats``
+            self.cache["moe_stats"] = jnp.zeros(len(MOE_STATS), jnp.int32)
         if self.tp.active:
             # place params and the KV pool on the mesh: attention weights
             # and cache split along (kv) heads, MLP along d_ff, everything
@@ -325,12 +337,12 @@ class DecodeEngine:
         # step paths need no mode guards
         self._partials: list[_PartialPrefill] = []
         self._prefill_slots: dict[int, _PartialPrefill] = {}
-        #: per-iteration counters behind sdiag's serve-step utilization;
-        #: ``iterations`` counts every ``step``, ``host_s`` its time
-        #: outside its ``engine.sync`` phases
-        self.serve_stats = {"iterations": 0, "decode_tokens": 0,
-                            "prefill_tokens": 0, "prefill_chunks": 0,
-                            "host_s": 0.0}
+        #: per-iteration counters behind sdiag's serve-step utilization
+        #: (``serve_stats``); ``iterations`` counts every ``step``,
+        #: ``host_s`` its time outside its ``engine.sync`` phases
+        self._serve_stats = {"iterations": 0, "decode_tokens": 0,
+                             "prefill_tokens": 0, "prefill_chunks": 0,
+                             "host_s": 0.0}
         self._synced = 0.0              # seconds in engine.sync so far
         self.max_batch_tokens: Optional[int] = None
         self._mixed_step = None
@@ -408,11 +420,22 @@ class DecodeEngine:
                 and self.prefix is not None
         self.index_generated = bool(index_generated)
 
+    @property
+    def serve_stats(self) -> dict:
+        """Additive counters: the serve step's, and on the paged path of a
+        model with experts the expert layers' (``MOE_STATS``), which are
+        read from the device here and only here."""
+        if "moe_stats" in self.cache:
+            self._serve_stats.update(zip(
+                MOE_STATS, np.asarray(self.cache["moe_stats"]).tolist()))
+        return self._serve_stats
+
     def _resolve_paging(self, kv_page_size: int,
                         kv_pages: Optional[int]) -> Optional[PagedKVConfig]:
         """Paged layout, or None (dense default).  Paging needs a cache
         without position-dependent physical layout: full attention (no
-        SSM state to page) and no sliding-window ring.  ``kv_pages``
+        SSM state to page) and no window shorter than the cache (a ring).
+        ``kv_pages``
         overrides the pool size; the default matches the dense HBM
         budget (num_slots * cache_len lines) plus the null page, so
         dense and paged engines are HBM-comparable out of the box."""
@@ -431,12 +454,16 @@ class DecodeEngine:
                 f"config, but cfg.attn_every={self.cfg.attn_every} "
                 "interleaves non-attention sublayers whose state has no "
                 "page layout")
-        if self.cfg.sliding_window is not None:
+        window = self.cfg.sliding_window
+        if window is not None and self.cache_len > window:
+            # a window no shorter than the cache changes nothing, so such
+            # layers page as full attention (models.model.layer_config)
             raise ValueError(
                 "kv_page_size: paged KV cache does not support "
-                f"cfg.sliding_window={self.cfg.sliding_window} — the "
-                "windowed ring cache's wrapped slot layout has no "
-                "page-table equivalent yet")
+                f"cfg.sliding_window={window} with cache_len="
+                f"{self.cache_len}, where the window drops lines — "
+                "windowed layers need rings of pages that free the pages "
+                "behind the window (ROADMAP Reach item 1)")
         assert self.cache_len % kv_page_size == 0, \
             (self.cache_len, kv_page_size)
         if kv_pages is not None:
@@ -547,7 +574,7 @@ class DecodeEngine:
                             lines, ((0, 0), (0, n * ps - length),
                                     (0, 0), (0, 0)))
                     return write_pages(pool_leaf, page_ids, lines)
-                return jax.tree.map(put, pool_cache, one_cache)
+                return DecodeEngine._merge(pool_cache, one_cache, put)
 
             return self._tp_wrap(insert_paged, "csr", "c", donate=(0,))
 
@@ -610,7 +637,17 @@ class DecodeEngine:
             layers = jnp.arange(pool_leaf.shape[0])[:, None]
             return write_lines(pool_leaf, layers, pages[None], offs[None],
                                one_leaf[:, 0])             # (G,C,K,Dh)
-        return jax.tree.map(put, cache, slices)
+        return DecodeEngine._merge(cache, slices, put)
+
+    @staticmethod
+    def _merge(pool, slices, put):
+        """``pool`` with ``put(pool leaf, slice leaf)`` over its layers,
+        and the expert counters the slices' program ended with."""
+        out = dict(pool, layers=jax.tree.map(put, pool["layers"],
+                                             slices["layers"]))
+        if "moe_stats" in slices:
+            out["moe_stats"] = slices["moe_stats"]
+        return out
 
     def _build_chunk_prefill(self):
         """Jitted standalone prefill chunk (budgeted mode): compute the
@@ -1458,7 +1495,7 @@ class DecodeEngine:
             req=req, toks=toks, slot=slot,
             pos_filled=len(shared) * self.paging.page_size,
             pages=list(shared), n_shared=len(shared), span=span,
-            admitted_iteration=self.serve_stats["iterations"])
+            admitted_iteration=self._serve_stats["iterations"])
         self._hold_pages(req, len(shared))
         self._partials.append(part)
         self._prefill_slots[slot] = part
@@ -1563,8 +1600,8 @@ class DecodeEngine:
             part = plan.part
             part.pos_filled = plan.start + plan.real
             part.chunks += 1
-            self.serve_stats["prefill_tokens"] += plan.real
-            self.serve_stats["prefill_chunks"] += 1
+            self._serve_stats["prefill_tokens"] += plan.real
+            self._serve_stats["prefill_chunks"] += 1
             if part.pos_filled >= len(part.toks):
                 self._partials.remove(part)
                 self._promote(part, logits)
@@ -1584,7 +1621,7 @@ class DecodeEngine:
             # holder refs were registered page-by-page as chunks grew
             self.prefix.insert(part.toks, part.pages)
         resume = bool(req.output)
-        iterations = (self.serve_stats["iterations"]
+        iterations = (self._serve_stats["iterations"]
                       - part.admitted_iteration + 1)
         if resume:
             tok = int(req.output[-1])      # resume: last token re-decodes
@@ -1670,7 +1707,7 @@ class DecodeEngine:
         chunks pack into the remainder — the head chunk FUSED into the
         same dispatch as decode, any leftover budget drained through
         standalone chunk dispatches."""
-        st = self.serve_stats
+        st = self._serve_stats
         T = self.max_batch_tokens
         tr = self.tracer
         with phase("engine.plan", tr):
@@ -1769,7 +1806,7 @@ class DecodeEngine:
         (``monitoring.trace.phase``): admit, plan, dispatch, sync, commit.
         ``serve_stats["host_s"]`` keeps the step's time outside its syncs.
         """
-        st = self.serve_stats
+        st = self._serve_stats
         t0, sync0 = time.perf_counter(), self._synced
         st["iterations"] += 1
         left = self._iterate()

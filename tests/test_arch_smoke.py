@@ -69,13 +69,14 @@ def test_full_config_exact_dims(arch_id):
         "mamba2-780m": (48, 1536, 0, 0, 0, 50280),
         "dbrx-132b": (40, 6144, 48, 8, 0, 100352),  # FFN is MoE (d_ff_expert below)
         "minitron-4b": (32, 3072, 24, 8, 9216, 256000),
+        "trinity-mini": (32, 2048, 32, 4, 6144, 200192),  # d_ff: dense
     }[arch_id]
     got = (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
            cfg.d_ff, cfg.vocab_size)
     assert got == expected, (arch_id, got, expected)
     # MoE expert hidden dims carry the published per-expert d_ff
     moe_dff = {"dbrx-132b": 10752, "qwen2-moe-a2.7b": 1408,
-               "jamba-1.5-large-398b": 24576}
+               "jamba-1.5-large-398b": 24576, "trinity-mini": 1024}
     if arch_id in moe_dff:
         assert cfg.moe is not None and cfg.moe.d_ff == moe_dff[arch_id]
 
@@ -93,6 +94,7 @@ def test_param_counts_match_family_scale():
         "mamba2-780m": (0.6e9, 0.95e9),
         "dbrx-132b": (110e9, 145e9),
         "minitron-4b": (3.5e9, 5.5e9),
+        "trinity-mini": (22e9, 30e9),      # 26B total (3B active)
     }
     for arch_id, (lo, hi) in expect.items():
         n = get_config(arch_id).param_count()
@@ -100,7 +102,8 @@ def test_param_counts_match_family_scale():
 
 
 def test_moe_active_params_less_than_total():
-    for arch_id in ("qwen2-moe-a2.7b", "dbrx-132b", "jamba-1.5-large-398b"):
+    for arch_id in ("qwen2-moe-a2.7b", "dbrx-132b", "jamba-1.5-large-398b",
+                    "trinity-mini"):
         cfg = get_config(arch_id)
         assert cfg.active_param_count() < cfg.param_count()
 

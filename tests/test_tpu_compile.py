@@ -159,3 +159,15 @@ def test_paged_decode_program_copies_no_pool(one_chip, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < layer_kv, (temp, layer_kv)
+
+
+def test_expert_kernels_at_trinity_widths(one_chip):
+    """trinity-mini's held experts: 32 SwiGLU experts of 1024 at d 2048,
+    over a decode pass's 512 pair rows, under their trace names."""
+    from repro.kernels.moe_gmm import expert_swiglu
+    G, d, f = 32, 2048, 1024
+    text = _compile(expert_swiglu, _sds(one_chip, (512, d)),
+                    _sds(one_chip, (G, d, f)), _sds(one_chip, (G, d, f)),
+                    _sds(one_chip, (G, f, d)),
+                    _sds(one_chip, (G,), jnp.int32))
+    assert "%moe_experts_up" in text and "%moe_experts_down" in text
