@@ -82,6 +82,8 @@ def flash_decode_paged(q, k, v, page_table, pos, layer, interpret=None):
     page_table: (B, n_pages) int32 mapping logical to physical pages (0 =
     null page); pos: (B,) int32 — attends logical positions [0, pos_b];
     layer: int32 scalar, the pool layer attended.  Returns (B, 1, H, Dh).
+    Each sequence reads only the ``cdiv(pos_b + 1, page_size)`` pages that
+    hold its lines, all kv heads of a page at once.
     """
     if interpret is None:
         interpret = _on_cpu()

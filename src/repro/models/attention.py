@@ -524,8 +524,9 @@ def attention_decode_paged(p: dict, x: jax.Array, cache: dict, layer,
     The new K/V line lands at physical ``(layer, page_table[b, pos//ps],
     :, pos % ps)``, a scatter of B lines into the stacked pool; nothing
     else of the pool is copied.  ``use_pallas`` hands the whole stacked
-    pool and ``layer`` to the paged kernel, which reads the table's pages
-    where they lie.  Otherwise attention gathers the layer through the
+    pool and ``layer`` to the paged kernel, which reads each sequence's
+    live pages (those holding [0, pos]) where they lie, and no page of
+    the table past them.  Otherwise attention gathers the layer through the
     page table into a (B, pages_per_seq*ps, K, Dh) logical view and runs
     exactly the dense full-cache math — masked logits are the same array
     the dense path produces, so greedy decode is bit-identical to the

@@ -36,6 +36,16 @@ def pages_for(tokens: int, page_size: int) -> int:
     return -(-int(tokens) // int(page_size)) if tokens > 0 else 0
 
 
+def page_visits(start: int, n: int, page_size: int) -> int:
+    """Pages the paged decode kernel reads for ``n`` tokens decoded from
+    position ``start``: the token at position p reads the pages holding
+    lines [0, p], ``p // page_size + 1`` of them."""
+    def upto(x):                  # summed over the positions below x
+        q, r = divmod(x, page_size)
+        return page_size * q * (q + 1) // 2 + r * (q + 1)
+    return upto(start + n) - upto(start)
+
+
 @dataclass(frozen=True)
 class PagedKVConfig:
     """Shape of one paged cache pool.

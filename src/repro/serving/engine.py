@@ -105,7 +105,7 @@ from repro.models.model import (
 )
 from repro.models.paging import (
     NULL_PAGE, PageAllocator, PagedKVConfig, ShardedAllocatorView,
-    TwoLevelPageTable, pages_for,
+    TwoLevelPageTable, page_visits, pages_for,
 )
 from repro.monitoring import MetricsRegistry, Tracer
 from repro.monitoring.trace import phase, stamp
@@ -339,10 +339,15 @@ class DecodeEngine:
         self._prefill_slots: dict[int, _PartialPrefill] = {}
         #: per-iteration counters behind sdiag's serve-step utilization
         #: (``serve_stats``); ``iterations`` counts every ``step``,
-        #: ``host_s`` its time outside its ``engine.sync`` phases
+        #: ``host_s`` its time outside its ``engine.sync`` phases.  Over
+        #: the paged decode dispatches' live lanes, ``kv_pages_visited``
+        #: sums the pages the decode kernel reads for each decoded token
+        #: (those holding its lines) and ``kv_pages_table`` the
+        #: dispatched table's width for each
         self._serve_stats = {"iterations": 0, "decode_tokens": 0,
                              "prefill_tokens": 0, "prefill_chunks": 0,
-                             "host_s": 0.0}
+                             "host_s": 0.0, "kv_pages_visited": 0,
+                             "kv_pages_table": 0}
         self._synced = 0.0              # seconds in engine.sync so far
         self.max_batch_tokens: Optional[int] = None
         self._mixed_step = None
@@ -1856,12 +1861,13 @@ class DecodeEngine:
         Returns ``(generated_tokens, chunk_outputs_or_None)``."""
         tr = self.tracer
         with phase("engine.dispatch", tr):
-            t0, out, chunk_out = self._dispatch_fused(num_tokens, chunk_plan)
+            t0, out, chunk_out, width = self._dispatch_fused(num_tokens,
+                                                             chunk_plan)
         # ONE sync per chunk: everything after it is host-side numpy
         with self._sync():
             out = [np.asarray(x) for x in out]
         with phase("engine.commit", tr):
-            total = self._commit_fused(active, *out, t0)
+            total = self._commit_fused(active, *out, t0, width)
         return total, chunk_out
 
     def _dispatch_fused(self, num_tokens: Optional[int],
@@ -1869,7 +1875,8 @@ class DecodeEngine:
         """Stage the lanes' arguments and dispatch the decode program
         (fused with ``chunk_plan`` when given).  Returns the dispatch
         time, the lane outputs to sync (tokens, pos, last token,
-        remaining, done) and the chunk's outputs."""
+        remaining, done), the chunk's outputs and the width of the page
+        table dispatched (0 unpaged)."""
         done = np.array([self.slots[i] is None or i in self._prefill_slots
                          for i in range(self.num_slots)])
         eos = np.array([
@@ -1880,8 +1887,10 @@ class DecodeEngine:
             (self.slots[i].temperature if self.slots[i] else 0.0)
             for i in range(self.num_slots)], np.float32)
         t0 = time.perf_counter()
-        chunk_out = None
+        chunk_out, width = None, 0
         if self.paging is not None:
+            table = self._dispatch_table()
+            width = table.shape[1]
             limit = np.array([
                 self._capacity(i) if self.slots[i] is not None
                 else self.cache_len
@@ -1897,8 +1906,7 @@ class DecodeEngine:
                     jnp.asarray(self.remaining.astype(np.int32)),
                     jnp.asarray(done), jnp.asarray(eos),
                     jnp.asarray(temps), self._key,
-                    jnp.asarray(self._dispatch_table()),
-                    jnp.asarray(limit),
+                    jnp.asarray(table), jnp.asarray(limit),
                     jnp.asarray(chunk_plan.tokens)[None],
                     jnp.asarray(chunk_plan.row)[None],
                     jnp.asarray(chunk_plan.start, jnp.int32),
@@ -1914,8 +1922,7 @@ class DecodeEngine:
                         jnp.asarray(self.remaining.astype(np.int32)),
                         jnp.asarray(done), jnp.asarray(eos),
                         jnp.asarray(temps), self._key,
-                        jnp.asarray(self._dispatch_table()),
-                        jnp.asarray(limit))
+                        jnp.asarray(table), jnp.asarray(limit))
         else:
             toks, self.cache, token, pos, remaining, done_d, self._key = \
                 self._decode_n(
@@ -1924,13 +1931,15 @@ class DecodeEngine:
                     jnp.asarray(self.remaining.astype(np.int32)),
                     jnp.asarray(done), jnp.asarray(eos), jnp.asarray(temps),
                     self._key)
-        return t0, (toks, pos, token, remaining, done_d), chunk_out
+        return t0, (toks, pos, token, remaining, done_d), chunk_out, width
 
     def _commit_fused(self, active: list, toks, pos, token, remaining,
-                      done_d, t0: float) -> int:
+                      done_d, t0: float, width: int) -> int:
         """Per-lane outputs, billing, finish and requeue after a fused
-        dispatch's sync.  Returns the tokens generated."""
+        dispatch's sync (``width``: its page table's).  Returns the tokens
+        generated."""
         tr = self.tracer
+        st = self._serve_stats
         self.metrics.histogram("serve_decode_seconds",
                                "batched decode-step latency").observe(
             time.perf_counter() - t0)
@@ -1951,6 +1960,9 @@ class DecodeEngine:
                     charges.append(
                         (req, n_gen, 0,
                          self._billed_pages(i) * n_gen))
+                    st["kv_pages_visited"] += page_visits(
+                        int(self.pos[i]), n_gen, self.paging.page_size)
+                    st["kv_pages_table"] += width * n_gen
                 else:
                     # per-chunk charge: n tokens + KV-line rent summed over
                     # the chunk's steps (sum_{j=1..n} pos0+j), exactly the

@@ -15,7 +15,7 @@ from repro.configs import RunConfig, get_reduced_config
 from repro.kernels import ops
 from repro.kernels.ref import paged_decode_attention_ref
 from repro.models.paging import (
-    NULL_PAGE, PageAllocator, PagedKVConfig, pages_for,
+    NULL_PAGE, PageAllocator, PagedKVConfig, page_visits, pages_for,
 )
 from repro.monitoring.metrics import METRIC_SERVE_PREEMPTIONS
 from repro.policy import FairShareTree, QOS
@@ -143,6 +143,82 @@ def test_paged_flash_decode_tails_and_page_boundaries(pos0):
             jnp.int32(layer), interpret=True)
         np.testing.assert_allclose(np.asarray(again), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
+
+
+LANE_WIDTHS = [
+    # (K, G, Dh): starcoder2-3b's 2:24 under TP=2 and whole, trinity-mini's
+    # 4:32, stablelm-3b's MHA heads of 80
+    (1, 12, 128), (2, 12, 128), (4, 8, 128), (32, 1, 80),
+]
+
+
+@pytest.mark.parametrize("K,G,Dh", LANE_WIDTHS)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("dead_pages", ["finite", "nan"])
+def test_paged_flash_decode_reads_only_live_pages(K, G, Dh, dtype,
+                                                  dead_pages):
+    """One batch of lanes at the edges of the page loop: one live line,
+    ``pos`` on the last line of a page and on the first of the next, a
+    full table, and a finished lane whose table is all null pages.  Each
+    lane's table runs on past its last live page into pages of its own;
+    filled with NaN (K and V) they must change nothing, so the kernel
+    does not read them."""
+    ps, npages, layers, layer = 8, 4, 2, 1
+    pos = np.array([0, ps - 1, ps, 2 * ps - 1, npages * ps - 1, 13],
+                   np.int32)
+    live = [p // ps + 1 for p in pos[:-1]]
+    pool = 1 + npages * (len(pos) - 1)
+    ids = iter(1 + RNG.permutation(pool - 1))
+    table = np.zeros((len(pos), npages), np.int32)    # the last lane: null
+    for b in range(len(pos) - 1):
+        table[b] = [next(ids) for _ in range(npages)]
+    dead = np.unique(np.concatenate(
+        [table[b, n:] for b, n in enumerate(live)]))
+    q = jnp.asarray(RNG.standard_normal((len(pos), 1, K * G, Dh)), dtype)
+    k = RNG.standard_normal((layers, pool, K, ps, Dh)).astype(np.float32)
+    v = RNG.standard_normal((layers, pool, K, ps, Dh)).astype(np.float32)
+    want = paged_decode_attention_ref(
+        q, jnp.asarray(k[layer], dtype), jnp.asarray(v[layer], dtype),
+        table, pos)
+    if dead_pages == "nan":
+        k[:, dead], v[:, dead] = np.nan, np.nan
+    got = ops.flash_decode_paged(q, jnp.asarray(k, dtype),
+                                 jnp.asarray(v, dtype), table, pos,
+                                 jnp.int32(layer), interpret=True)
+    got = np.asarray(got, np.float32)
+    assert np.isfinite(got).all()
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(got, np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+
+
+def test_page_visits_counts_each_tokens_live_pages():
+    for start in range(0, 40):
+        for n in range(0, 20):
+            assert page_visits(start, n, 8) == sum(
+                p // 8 + 1 for p in range(start, start + n))
+
+
+def test_engine_counts_kv_pages_visited(tiny_model):
+    """``kv_pages_visited`` / ``kv_pages_table`` against a hand count.
+    A request of prompt P and N new tokens decodes N - 1 of them in
+    decode dispatches, at positions P .. P+N-2 (the first comes from its
+    prefill); the token at p reads p // 8 + 1 pages of 8 lines, and the
+    budgeted engine dispatches the whole 8-page table.  In chunks of 4:
+    A decodes positions 5, 6 and finishes mid-chunk; B decodes 6, 7, 8,
+    9 (crossing into its second page mid-chunk) and then 10; C decodes
+    17 alone."""
+    cfg, params = tiny_model
+    rng = np.random.default_rng(5)
+    reqs = [Request(rid=i, prompt=rng.integers(0, cfg.vocab_size, plen)
+                    .astype(np.int32), max_new_tokens=new)
+            for i, (plen, new) in enumerate([(5, 3), (6, 6), (17, 2)])]
+    eng = _run(cfg, params, reqs, num_slots=3, cache_len=64,
+               kv_page_size=8, max_batch_tokens=32, decode_chunk=4)
+    st = eng.serve_stats
+    assert st["decode_tokens"] == 2 + 5 + 1
+    assert st["kv_pages_visited"] == (1 + 1) + (1 + 1 + 2 + 2 + 2) + 3
+    assert st["kv_pages_table"] == 8 * (2 + 5 + 1)
 
 
 # ------------------------------------------------------ engine identity ----

@@ -25,6 +25,10 @@ from repro.kernels import ops
 # stablelm-3b: MHA, head_dim 80; qwen2-7b: GQA 28 query / 4 kv heads, 128
 STABLELM = dict(H=32, K=32, Dh=80)
 QWEN2 = dict(H=28, K=4, Dh=128)
+# the benchmark's cells: starcoder2-3b GQA 24:2, trinity-mini 32:4, heads
+# of 128
+STARCODER2 = dict(H=24, K=2, Dh=128)
+TRINITY = dict(H=32, K=4, Dh=128)
 B = 4
 
 
@@ -83,21 +87,43 @@ def test_ring_decode(one_chip):
              _sds(one_chip, (B,), jnp.int32))
 
 
-@pytest.mark.parametrize("widths", [STABLELM, QWEN2],
-                         ids=["mha_dh80", "gqa_dh128"])
-def test_paged_decode(one_chip, widths):
-    """A 1024-line page table over a 257-page pool of 16-line pages, one
-    layer of a 4-layer stacked pool read in place."""
+def _pallas_grids(jaxpr):
+    """The grid of every pallas_call in ``jaxpr``, nested ones too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield tuple(eqn.params["grid_mapping"].grid)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_grids(sub)
+
+
+@pytest.mark.parametrize("widths,lanes,layers,pages,ps,per_seq", [
+    (STABLELM, B, 4, 257, 16, 64),
+    (QWEN2, B, 4, 257, 16, 64),
+    (STARCODER2, 64, 30, 1025, 128, 16),
+    (TRINITY, 64, 12, 1025, 128, 16),
+], ids=["mha_dh80", "gqa_dh128", "starcoder2_3b", "trinity_mini"])
+def test_paged_decode(one_chip, widths, lanes, layers, pages, ps, per_seq):
+    """A page table of ``per_seq`` pages over a stacked pool, one layer
+    (traced) read in place: 16-line pages under a 64-page table, and the
+    cells' 128-line pages under a 16-page table for 64 lanes.  Where the
+    head dim fills the 128-lane tiling, the kernel's grid is one cell per
+    lane: the table's width is no grid axis."""
     H, K, Dh = widths.values()
-    layers, pages, ps, per_seq = 4, 257, 16, 64
-    _compile(lambda q, k, v, pt, p, l: ops.flash_decode_paged(
-                 q, k, v, pt, p, l, interpret=False),
-             _sds(one_chip, (B, 1, H, Dh)),
-             _sds(one_chip, (layers, pages, K, ps, Dh)),
-             _sds(one_chip, (layers, pages, K, ps, Dh)),
-             _sds(one_chip, (B, per_seq), jnp.int32),
-             _sds(one_chip, (B,), jnp.int32),
-             _sds(one_chip, (), jnp.int32))
+
+    def fn(q, k, v, pt, p, l):
+        return ops.flash_decode_paged(q, k, v, pt, p, l, interpret=False)
+
+    shapes = (_sds(one_chip, (lanes, 1, H, Dh)),
+              _sds(one_chip, (layers, pages, K, ps, Dh)),
+              _sds(one_chip, (layers, pages, K, ps, Dh)),
+              _sds(one_chip, (lanes, per_seq), jnp.int32),
+              _sds(one_chip, (lanes,), jnp.int32),
+              _sds(one_chip, (), jnp.int32))
+    _compile(fn, *shapes)
+    grids = list(_pallas_grids(jax.make_jaxpr(fn)(*shapes).jaxpr))
+    assert len(grids) == 1, grids
+    if Dh % 128 == 0:
+        assert grids[0] == (lanes,) and per_seq not in grids[0], grids
 
 
 def test_flash_attention(one_chip):
